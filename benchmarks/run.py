@@ -49,6 +49,9 @@ def main() -> None:
                     help="path for the pr10 bench JSON (default: BENCH_PR10.json)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.paper_figs import ALL_BENCHES
 
     selected = (
@@ -59,6 +62,7 @@ def main() -> None:
            "pr10", "roofline"]
     )
     print("name,value,derived")
+    errors = 0
     for name in selected:
         t0 = time.perf_counter()
         try:
@@ -117,10 +121,13 @@ def main() -> None:
                 bench_rows = ALL_BENCHES[name]()
         except Exception as e:  # noqa: BLE001
             print(f"{name}/ERROR,0,{type(e).__name__}: {e}")
+            errors += 1
             continue
         for row_name, value, derived in bench_rows:
             print(f"{row_name},{value:.6g},{derived}")
         print(f"{name}/bench_wall_s,{time.perf_counter() - t0:.1f},harness timing")
+    if errors:
+        sys.exit(f"{errors} bench(es) printed an ERROR row")
 
 
 if __name__ == "__main__":

@@ -25,6 +25,7 @@ the copies instead.  Its PATS profile is derived from the fused ops'
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import numpy as np
@@ -123,6 +124,7 @@ def _wrap(fn, to_host: bool = False):
     implementations) downloads accelerator-produced input arrays.
     """
 
+    @functools.wraps(fn)
     def impl(ctx: OpContext):
         if not ctx.inputs:
             return fn(ctx.chunk.payload)
@@ -182,7 +184,7 @@ def register_variants(
 
 def _register_pallas_variants(reg: VariantRegistry) -> None:
     """Bind the Pallas kernels as ``tpu`` variants of their ops
-    (interpret-mode on CPU; compiled on real TPUs)."""
+    (compiled on TPU, interpret mode on the CPU backend)."""
     import jax.numpy as jnp
 
     from ..kernels import ops as K
@@ -207,7 +209,7 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
         marker = inv
         for _ in range(8):
             marker = _erode_j(marker)
-        recon = K.morph_recon(marker, inv, stripe=64, inner_iters=16)
+        recon = K.morph_recon(marker, inv)
         nuclei = ((inv - recon) > 25.0) & jnp.asarray(state["fg_open"])
         return {**state, "recon": recon, "nuclei": nuclei}
 
@@ -220,7 +222,7 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
         rgb = np.asarray(state["rgb"], np.float32)
         hema, eosin, mag, _ = K.feature_fused(
             jnp.asarray(rgb[..., 0]), jnp.asarray(rgb[..., 1]),
-            jnp.asarray(rgb[..., 2]), stripe=128,
+            jnp.asarray(rgb[..., 2]),
         )
         objects = jnp.asarray(state["objects"])
         return {

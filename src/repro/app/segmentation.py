@@ -41,7 +41,9 @@ __all__ = [
     "label_image_np", "morph_reconstruct_np",
 ]
 
-MAX_OBJECTS = 256  # per-tile cap used by fixed-shape accel kernels
+# Per-tile object cap of the fixed-shape accelerator features: above
+# the most nuclei a generated 4096² tile holds (6,656).
+MAX_OBJECTS = 8192
 
 
 # --------------------------------------------------------------------------

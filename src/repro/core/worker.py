@@ -59,6 +59,44 @@ from ..telemetry.tracing import SpanContext, current_context, use_context
 __all__ = ["DeviceMemory", "LaneSpec", "OpContext", "WorkerRuntime"]
 
 
+def _lane_device(spec: "LaneSpec") -> Any:
+    """The device accelerator lane ``spec`` drives: ``jax.devices()[index]``
+    (one control thread per chip, paper §IV-A)."""
+    import jax
+
+    devices = jax.devices()
+    if spec.index >= len(devices):
+        raise ValueError(
+            f"lane {spec.kind}{spec.index} needs device {spec.index}, but "
+            f"the {jax.default_backend()} backend has {len(devices)}"
+        )
+    return devices[spec.index]
+
+
+def _to_host(value: Any) -> Any:
+    """Download: ``value`` with every device array replaced by its host
+    (NumPy) copy; values holding no device array pass through as is."""
+    import jax
+
+    if any(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(value)):
+        return jax.device_get(value)
+    return value
+
+
+def _place(value: Any, device: Any) -> Any:
+    """Upload: move device arrays that live on another device to ``device``
+    (host arrays are uploaded by the op itself, on the lane's default
+    device)."""
+    import jax
+
+    def put(x):
+        if isinstance(x, jax.Array) and x.devices() != {device}:
+            return jax.device_put(x, device)
+        return x
+
+    return jax.tree_util.tree_map(put, value)
+
+
 class DeviceMemory:
     """LRU store emulating an accelerator's discrete memory.
 
@@ -125,6 +163,7 @@ class _LaneState:
     spec: LaneSpec
     thread: Optional[threading.Thread] = None
     memory: Optional[DeviceMemory] = None
+    device: Any = None  # accelerator lanes: the jax device they drive
     busy_seconds: float = 0.0
     executed: int = 0
     busy: bool = False  # currently executing (work-conserving batching)
@@ -272,6 +311,11 @@ class WorkerRuntime:
         # (predictive push of sink outputs) before the lease's own pull.
         self.push_ingested = c("push_ingested")
         self.push_ingested_bytes = c("push_ingested_bytes")
+        # Ops an accelerator lane ran through the host implementation
+        # because their variant has none for the lane's kind, and every
+        # op run by (op name, implementation kind).
+        self.host_fallbacks = c("host_fallbacks")
+        self.variant_runs: dict[tuple[str, str], int] = {}
         # Trace context per leased stage: captured at submit time (the
         # TracingBus installs the sender's context around the handler)
         # and re-installed around op execution and the completion
@@ -305,6 +349,9 @@ class WorkerRuntime:
     def start(self) -> None:
         if self.agent is not None:
             self.agent.start()
+        for lane in self._lanes:
+            if lane.memory is not None:
+                lane.device = _lane_device(lane.spec)
         for lane in self._lanes:
             t = threading.Thread(
                 target=self._lane_loop, args=(lane,), daemon=True,
@@ -640,6 +687,10 @@ class WorkerRuntime:
             "batched_ops": int(self.scheduler.stats.batched_ops),
             "push_ingested": int(self.push_ingested),
             "push_ingested_bytes": int(self.push_ingested_bytes),
+            "host_fallbacks": int(self.host_fallbacks),
+            "variant_runs": {
+                f"{op}/{kind}": n for (op, kind), n in self.variant_runs.items()
+            },
             "staging": self.store.stats(),
             "prefetch": self.agent.stats() if self.agent is not None else {},
         }
@@ -654,6 +705,15 @@ class WorkerRuntime:
     # -- lane main loop -----------------------------------------------------------
 
     def _lane_loop(self, lane: _LaneState) -> None:
+        if lane.device is None:
+            return self._serve_lane(lane)
+        import jax
+
+        # Arrays an op creates land on the lane's own device.
+        with jax.default_device(lane.device):
+            self._serve_lane(lane)
+
+    def _serve_lane(self, lane: _LaneState) -> None:
         while True:
             with self._lock:
                 lane.busy = False
@@ -748,6 +808,16 @@ class WorkerRuntime:
         batch_fn = (
             var.batch_implementation(lane.spec.kind) if len(ois) > 1 else None
         )
+        kind = (
+            lane.spec.kind
+            if batch_fn is not None or var.supports(lane.spec.kind)
+            else HOST_KIND
+        )
+        with self._lock:
+            key = (var.name, kind)
+            self.variant_runs[key] = self.variant_runs.get(key, 0) + len(ois)
+            if kind != lane.spec.kind:
+                self.host_fallbacks += len(ois)
         failures: list[tuple[OperationInstance, BaseException]] = []
         if batch_fn is not None:
             for oi in ois:
@@ -943,7 +1013,9 @@ class WorkerRuntime:
                 if lane.memory is not None:
                     if uid not in lane.memory:
                         lane.memory.uploads += 1
-                        self._device_put_locked(lane, uid, value)
+                        self._device_put_locked(
+                            lane, uid, _place(value, lane.device)
+                        )
                     inputs[name] = lane.memory.get(uid)
                 else:
                     inputs[name] = value
@@ -957,7 +1029,7 @@ class WorkerRuntime:
             if self._device_only.pop(e_uid, None) is not None:
                 lane.memory.downloads += 1
                 self.chain_writebacks += 1
-                self.store.put(op_key(e_uid), e_val)
+                self.store.put(op_key(e_uid), _to_host(e_val))
                 # Same invariant as _commit/_materialize: keep the only
                 # host copy resident until its consumers ran.
                 self.store.pin(op_key(e_uid))
@@ -974,7 +1046,7 @@ class WorkerRuntime:
         holder = self._device_only.get(uid)
         if holder is None or holder.memory is None or uid not in holder.memory:
             return None
-        value = holder.memory.get(uid)
+        value = _to_host(holder.memory.get(uid))
         del self._device_only[uid]
         holder.memory.downloads += 1
         self.chain_writebacks += 1
@@ -1036,7 +1108,13 @@ class WorkerRuntime:
                 self._host_chained[oi.uid] = out
                 self.host_chain_deferred += 1
             else:
-                self.store.put(op_key(oi.uid), out)  # host write-back (download)
+                # Host write-back: the host tier holds host bytes, so an
+                # accelerator lane downloads here, and only its bounded
+                # DeviceMemory keeps device buffers alive.
+                self.store.put(
+                    op_key(oi.uid),
+                    _to_host(out) if lane.memory is not None else out,
+                )
                 # Keep the output resident until its consumers (and the
                 # stage-completion read below) ran: tier budgets are a
                 # soft cap for the live working set, never a correctness

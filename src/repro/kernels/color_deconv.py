@@ -35,7 +35,7 @@ def color_deconv_pallas(
     b: jnp.ndarray,
     *,
     block: tuple[int, int] = (256, 256),
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     h, w = r.shape
     bh, bw = min(block[0], h), min(block[1], w)

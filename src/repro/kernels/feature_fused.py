@@ -11,9 +11,9 @@ the luminance, and the per-stripe partial moments of hema and |grad|
 of three is exactly the memory-roofline move that makes fine-grain
 chained ops competitive with a monolithic kernel.
 
-Layout follows ``sobel_stats``: row-stripe blocking with one
-edge-replicated halo row per side; channel planes are separate (H, W)
-arrays so every load is a contiguous lane-aligned tile.
+Layout follows ``sobel_stats``: row-stripe blocking with a one-tile
+halo per side (:mod:`repro.kernels.stencil`); channel planes are
+separate (H, W) arrays so every load is a contiguous lane-aligned tile.
 """
 
 from __future__ import annotations
@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .ref import DECONV_MATRIX, GRAY_WEIGHTS
+from .sobel_stats import sobel_mag
+from .stencil import TILE, halo_rows, lane_tile, stripe_specs
 
 __all__ = ["feature_fused_pallas"]
 
@@ -47,12 +49,11 @@ def _kernel(
     g_up, g_c, g_dn,
     b_up, b_c, b_dn,
     hema_ref, eosin_ref, mag_ref, stats_ref,
-    *, m,
+    *, m, hb,
 ):
     i = pl.program_id(0)
     n = pl.num_programs(0)
     rc, gc, bc = r_c[...], g_c[...], b_c[...]
-    rows, w = rc.shape
 
     # Stain separation on the center stripe (pure VPU elementwise).
     odr, odg, odb = _od(rc), _od(gc), _od(bc)
@@ -61,42 +62,28 @@ def _kernel(
     hema_ref[...] = hema
     eosin_ref[...] = eosin
 
-    # Sobel of the luminance with edge-replicated halo rows: real
-    # neighbour rows inside the image, the stripe's own boundary row at
-    # the image border (matches jnp.pad mode="edge" in the oracle).
+    # Sobel of the luminance with edge-replicated halo rows: the last
+    # row of the tile above and the first of the tile below inside the
+    # image, the stripe's own boundary row at the image border.
     gray_c = _gray(rc, gc, bc)
-    up_row = jnp.where(
+    above = jnp.where(
         i == 0,
         gray_c[:1, :],
-        _gray(r_up[...][-1:, :], g_up[...][-1:, :], b_up[...][-1:, :]),
+        _gray(r_up[hb - 1:hb, :], g_up[hb - 1:hb, :], b_up[hb - 1:hb, :]),
     )
-    dn_row = jnp.where(
+    below = jnp.where(
         i == n - 1,
         gray_c[-1:, :],
-        _gray(r_dn[...][:1, :], g_dn[...][:1, :], b_dn[...][:1, :]),
+        _gray(r_dn[0:1, :], g_dn[0:1, :], b_dn[0:1, :]),
     )
-    ext = jnp.concatenate([up_row, gray_c, dn_row], axis=0)  # (rows+2, W)
-    ext = jnp.concatenate([ext[:, :1], ext, ext[:, -1:]], axis=1)
-    sl = lambda dy, dx: jax.lax.dynamic_slice(ext, (dy, dx), (rows, w))
-    gx = (
-        -1.0 * sl(0, 0) + 1.0 * sl(0, 2)
-        - 2.0 * sl(1, 0) + 2.0 * sl(1, 2)
-        - 1.0 * sl(2, 0) + 1.0 * sl(2, 2)
-    )
-    gy = (
-        -1.0 * sl(0, 0) - 2.0 * sl(0, 1) - 1.0 * sl(0, 2)
-        + 1.0 * sl(2, 0) + 2.0 * sl(2, 1) + 1.0 * sl(2, 2)
-    )
-    mag = jnp.sqrt(gx * gx + gy * gy)
+    mag = sobel_mag(gray_c, above, below)
     mag_ref[...] = mag
 
     # Per-stripe partial moments, reduced on the host.
-    stats_ref[0, 0] = hema.sum()
-    stats_ref[0, 1] = (hema * hema).sum()
-    stats_ref[0, 2] = hema.max()
-    stats_ref[0, 3] = mag.sum()
-    stats_ref[0, 4] = (mag * mag).sum()
-    stats_ref[0, 5] = mag.max()
+    stats_ref[...] = lane_tile([
+        hema.sum(), (hema * hema).sum(), hema.max(),
+        mag.sum(), (mag * mag).sum(), mag.max(),
+    ])
 
 
 @functools.partial(jax.jit, static_argnames=("stripe", "interpret"))
@@ -105,8 +92,8 @@ def feature_fused_pallas(
     g: jnp.ndarray,
     b: jnp.ndarray,
     *,
-    stripe: int = 128,
-    interpret: bool = True,
+    stripe: int = 32,
+    interpret: bool = False,
 ):
     """Fused deconv + hema moments + Sobel-of-luminance moments.
 
@@ -119,30 +106,24 @@ def feature_fused_pallas(
     if h % bh:
         raise ValueError(f"height {h} not divisible by stripe {bh}")
     n = h // bh
-    clamp = lambda i: jnp.clip(i, 0, n - 1)
-    spec_up = pl.BlockSpec((bh, w), lambda i: (clamp(i - 1), 0))
-    spec_c = pl.BlockSpec((bh, w), lambda i: (i, 0))
-    spec_dn = pl.BlockSpec((bh, w), lambda i: (clamp(i + 1), 0))
+    hb = halo_rows(r.dtype)
+    up, mid, dn = stripe_specs(h, w, bh, hb)
     m = tuple(tuple(float(x) for x in row) for row in DECONV_MATRIX)
     plane = jax.ShapeDtypeStruct((h, w), jnp.float32)
     hema, eosin, mag, partial = pl.pallas_call(
-        functools.partial(_kernel, m=m),
+        functools.partial(_kernel, m=m, hb=hb),
         grid=(n,),
-        in_specs=[spec_up, spec_c, spec_dn] * 3,
-        out_specs=(
-            spec_c,
-            spec_c,
-            spec_c,
-            pl.BlockSpec((1, 6), lambda i: (i, 0)),
-        ),
+        in_specs=[up, mid, dn] * 3,
+        out_specs=(mid, mid, mid, pl.BlockSpec(TILE, lambda i: (i, 0))),
         out_shape=(
             plane,
             plane,
             plane,
-            jax.ShapeDtypeStruct((n, 6), jnp.float32),
+            jax.ShapeDtypeStruct((n * TILE[0], TILE[1]), jnp.float32),
         ),
         interpret=interpret,
     )(r, r, r, g, g, g, b, b, b)
+    partial = partial.reshape(n, TILE[0], TILE[1])[:, 0, :6]
     stats = jnp.stack(
         [
             partial[:, 0].sum(),

@@ -12,7 +12,8 @@ Vincent's sequential reconstruction (the fixpoint is unique and
 propagation order only affects the iteration count).
 
 Stripes keep the lane dimension = image width (multiple of 128), so
-every vector op is fully populated.
+every vector op is fully populated; the halo is one 8-row tile per
+stripe edge (:mod:`repro.kernels.stencil`).
 """
 
 from __future__ import annotations
@@ -23,44 +24,34 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .stencil import TILE, halo_rows, hshift, lane_tile, stripe_specs, vshift
+
 __all__ = ["morph_recon_pallas", "morph_recon_step"]
 
 _NEG = -3.0e38  # effectively -inf for f32 image data
 
 
-def _dilate8_in_block(x: jnp.ndarray) -> jnp.ndarray:
-    """8-connected max over a (rows, W) tile; -inf beyond all edges."""
-    p = jnp.pad(x, ((1, 1), (1, 1)), constant_values=_NEG)
-    r, w = x.shape
-    out = x
-    for dy in range(3):
-        for dx in range(3):
-            out = jnp.maximum(out, jax.lax.dynamic_slice(p, (dy, dx), (r, w)))
-    return out
-
-
-def _kernel(up_ref, c_ref, dn_ref, mask_ref, out_ref, changed_ref, *, inner_iters):
+def _kernel(up_ref, c_ref, dn_ref, mask_ref, out_ref, changed_ref, *,
+            inner_iters, hb):
     i = pl.program_id(0)
     n = pl.num_programs(0)
     c = c_ref[...]
     mask = mask_ref[...]
-    w = c.shape[1]
-    neg_row = jnp.full((1, w), _NEG, c.dtype)
-    up_row = jnp.where(i == 0, neg_row, up_ref[...][-1:, :])
-    dn_row = jnp.where(i == n - 1, neg_row, dn_ref[...][:1, :])
+    # Halo rows stay fixed until the next outer exchange; beyond the
+    # image they are -inf.
+    above = jnp.where(i == 0, _NEG, up_ref[hb - 1:hb, :])
+    below = jnp.where(i == n - 1, _NEG, dn_ref[0:1, :])
 
-    def sweep(_, ext):
-        d = _dilate8_in_block(ext)
-        # Only interior (center-stripe) rows are updated; halo rows stay
-        # fixed until the next outer exchange.
-        new_c = jnp.minimum(d[1:-1, :], mask)
-        return jnp.concatenate([ext[:1], new_c, ext[-1:]], axis=0)
+    def sweep(_, x):
+        # 8-connected max, separably: rows first, then columns.
+        up, dn = vshift(x, above, below)
+        v = jnp.maximum(jnp.maximum(up, x), dn)
+        lf, rt = hshift(v, _NEG, _NEG)
+        return jnp.minimum(jnp.maximum(jnp.maximum(lf, v), rt), mask)
 
-    ext0 = jnp.concatenate([up_row, c, dn_row], axis=0)
-    ext = jax.lax.fori_loop(0, inner_iters, sweep, ext0)
-    new_c = ext[1:-1, :]
+    new_c = jax.lax.fori_loop(0, inner_iters, sweep, c)
     out_ref[...] = new_c
-    changed_ref[0, 0] = jnp.any(new_c != c).astype(jnp.int32)
+    changed_ref[...] = lane_tile([jnp.max(jnp.where(new_c != c, 1.0, 0.0))])
 
 
 @functools.partial(jax.jit, static_argnames=("stripe", "inner_iters", "interpret"))
@@ -68,9 +59,9 @@ def morph_recon_step(
     marker: jnp.ndarray,
     mask: jnp.ndarray,
     *,
-    stripe: int = 128,
+    stripe: int = 64,
     inner_iters: int = 16,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """One outer block-synchronous sweep. Returns (new_marker, changed)."""
     h, w = marker.shape
@@ -78,23 +69,16 @@ def morph_recon_step(
     if h % bh:
         raise ValueError(f"height {h} not divisible by stripe {bh}")
     n = h // bh
-    clamp = lambda i: jnp.clip(i, 0, n - 1)
+    hb = halo_rows(marker.dtype)
+    up, mid, dn = stripe_specs(h, w, bh, hb)
     new_marker, changed = pl.pallas_call(
-        functools.partial(_kernel, inner_iters=inner_iters),
+        functools.partial(_kernel, inner_iters=inner_iters, hb=hb),
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((bh, w), lambda i: (clamp(i - 1), 0)),  # up stripe
-            pl.BlockSpec((bh, w), lambda i: (i, 0)),             # center
-            pl.BlockSpec((bh, w), lambda i: (clamp(i + 1), 0)),  # down stripe
-            pl.BlockSpec((bh, w), lambda i: (i, 0)),             # mask
-        ],
-        out_specs=(
-            pl.BlockSpec((bh, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ),
+        in_specs=[up, mid, dn, mid],
+        out_specs=(mid, pl.BlockSpec(TILE, lambda i: (i, 0))),
         out_shape=(
             jax.ShapeDtypeStruct((h, w), marker.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n * TILE[0], TILE[1]), jnp.float32),
         ),
         interpret=interpret,
     )(marker, marker, marker, mask)
@@ -106,9 +90,9 @@ def morph_recon_pallas(
     marker: jnp.ndarray,
     mask: jnp.ndarray,
     *,
-    stripe: int = 128,
+    stripe: int = 64,
     inner_iters: int = 16,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Run block-synchronous sweeps to the global fixpoint."""
     marker = jnp.minimum(marker.astype(jnp.float32), mask.astype(jnp.float32))

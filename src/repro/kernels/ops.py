@@ -1,10 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels (function variants).
 
-Each wrapper picks the right execution mode for the current backend:
+Each wrapper picks the execution mode for the current backend:
 
 * on TPU — the compiled Pallas kernel,
-* elsewhere — the same kernel body in interpret mode (correctness), or
-  the jnp oracle when the caller asks for speed on CPU.
+* on CPU — the same kernel body in interpret mode (correctness),
+* on any other backend — an error, never a silent interpreter.
 
 These are registered as the ``tpu`` function variants of the
 corresponding logical operations, so the middleware's variant mechanism
@@ -46,8 +46,15 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@functools.lru_cache(maxsize=1)
 def _interpret() -> bool:
-    return not on_tpu()
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"the {backend!r} backend has neither"
+        )
+    return backend == "cpu"
 
 
 def color_deconv(r, g, b, **kw):

@@ -14,13 +14,22 @@ from dataclasses import dataclass
 
 import jax
 
-__all__ = ["make_production_mesh", "MeshAxes", "axes_for"]
+__all__ = ["make_production_mesh", "auto_axes", "MeshAxes", "axes_for"]
+
+
+def auto_axes(mesh):
+    """``mesh`` with every axis Auto, whatever axis types it carries.
+
+    The sharding rules are GSPMD-style (the compiler propagates
+    activation shardings), not JAX's default Explicit typing."""
+    auto = jax.sharding.AxisType.Auto
+    return mesh.update(axis_types=(auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_axes(jax.make_mesh(shape, axes))
 
 
 @dataclass(frozen=True)

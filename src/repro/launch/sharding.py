@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import ShapeSpec
 from ..models.config import ArchConfig
-from .mesh import MeshAxes
+from .mesh import MeshAxes, auto_axes
 
 __all__ = [
     "param_specs",
@@ -202,6 +202,7 @@ def cache_specs(cache_shapes: Any, cfg: ArchConfig, ax: MeshAxes, mesh,
 
 
 def to_shardings(spec_tree: Any, mesh) -> Any:
+    mesh = auto_axes(mesh)
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P),

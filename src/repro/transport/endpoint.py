@@ -45,6 +45,7 @@ thread state is never forked mid-flight) from a picklable
 
 from __future__ import annotations
 
+import glob
 import importlib
 import queue
 import threading
@@ -989,8 +990,33 @@ def _resolve_factory(path: str) -> Callable[[], Any]:
     return getattr(importlib.import_module(module), attr)
 
 
+def _has_accelerator(spec: WorkerSpec) -> bool:
+    from ..core.scheduling import HOST_KIND
+
+    return any(kind != HOST_KIND for kind, _ in spec.lanes)
+
+
+def host_chips() -> int:
+    """TPU chips attached to this host, counted from their device nodes,
+    so that counting opens no chip."""
+    return len(glob.glob("/dev/accel[0-9]*")) + len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+#: spawned workers with accelerator lanes (they hold the host's chips)
+_accel_children: list = []
+
+
 def worker_main(address: str, spec: WorkerSpec) -> None:
     """Entry point of a spawned worker process: build, bridge, serve."""
+    import jax
+
+    from ..compile_cache import enable_compile_cache
+
+    if not _has_accelerator(spec):
+        # Before any backend initialises: a host-only worker never
+        # opens the chip, which belongs to one process at a time.
+        jax.config.update("jax_platforms", "cpu")
+    enable_compile_cache()
     from ..core.worker import LaneSpec, WorkerRuntime
     from ..staging import StagingConfig
 
@@ -1049,9 +1075,23 @@ def worker_main(address: str, spec: WorkerSpec) -> None:
 
 
 def spawn_worker(address: str, spec: WorkerSpec):
-    """Launch ``worker_main`` in a fresh OS process (spawn context)."""
+    """Launch ``worker_main`` in a fresh OS process (spawn context).
+
+    A JAX process takes every chip of its host, so a worker with
+    accelerator lanes is refused while another one lives, and on a host
+    without chips; its lanes drive the host's chips (lane *i* on chip
+    *i*)."""
     import multiprocessing as mp
 
+    if _has_accelerator(spec):
+        _accel_children[:] = [p for p in _accel_children if p.is_alive()]
+        chips = host_chips()
+        if chips == 0 or _accel_children:
+            raise RuntimeError(
+                f"worker {spec.worker_id} has accelerator lanes {spec.lanes}, "
+                f"but this host has {chips} chip(s) and "
+                f"{len(_accel_children)} live worker(s) already hold them"
+            )
     ctx = mp.get_context("spawn")
     proc = ctx.Process(
         target=worker_main,
@@ -1060,4 +1100,6 @@ def spawn_worker(address: str, spec: WorkerSpec):
         name=f"repro-worker-{spec.worker_id}",
     )
     proc.start()
+    if _has_accelerator(spec):
+        _accel_children.append(proc)
     return proc

@@ -89,12 +89,15 @@ class SocketPeer(Peer):
         handlers: dict[str, Handler],
         bus: "SocketBus",
         name: str,
+        on_disconnect: Optional[Callable[[Peer], None]] = None,
     ) -> None:
         self.name = name
         self.bus = bus
         self.handlers = dict(handlers)
         self.codec = bus.codec
-        self.on_disconnect: Optional[Callable[[Peer], None]] = None
+        # Set before the receiver starts: a peer that hangs up at once
+        # must still be reported.
+        self.on_disconnect = on_disconnect
         self._sock = sock
         self._sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self._send_lock = threading.Lock()
@@ -388,8 +391,8 @@ class SocketBus(MessageBus):
                 except OSError:
                     return
                 n += 1
-                peer = SocketPeer(sock, handlers, self, f"{address}<-{addr[1]}")
-                peer.on_disconnect = on_disconnect
+                peer = SocketPeer(sock, handlers, self, f"{address}<-{addr[1]}",
+                                  on_disconnect=on_disconnect)
                 with self._lock:
                     self._peers.append(peer)
                 if on_connect is not None:
