@@ -41,8 +41,6 @@ def main() -> None:
                     help="path for the pr6 bench JSON (default: BENCH_PR6.json)")
     ap.add_argument("--pr7-json", default=None,
                     help="path for the pr7 bench JSON (default: BENCH_PR7.json)")
-    ap.add_argument("--pr8-json", default=None,
-                    help="path for the pr8 bench JSON (default: BENCH_PR8.json)")
     ap.add_argument("--pr9-json", default=None,
                     help="path for the pr9 bench JSON (default: BENCH_PR9.json)")
     ap.add_argument("--pr10-json", default=None,
@@ -58,7 +56,7 @@ def main() -> None:
         args.only.split(",")
         if args.only
         else list(ALL_BENCHES)
-        + ["staging", "pr2", "pr3", "pr4", "pr5", "pr6", "pr7", "pr8", "pr9",
+        + ["staging", "pr2", "pr3", "pr4", "pr5", "pr6", "pr7", "pr9",
            "pr10", "roofline"]
     )
     print("name,value,derived")
@@ -90,10 +88,6 @@ def main() -> None:
                 from benchmarks.faults import bench_pr7
 
                 bench_rows = bench_pr7(args.pr7_json)
-            elif name == "pr8":
-                from benchmarks.telemetry import bench_pr8
-
-                bench_rows = bench_pr8(args.pr8_json)
             elif name == "pr9":
                 from benchmarks.degradation import bench_pr9
 

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -235,6 +235,14 @@ class Manager:
         # context) and re-installed around the lease so the trace
         # follows the stage to whichever worker wins it.
         self._trace_ctx: dict[int, SpanContext] = {}
+        # A queueing with no context behind it (the batch path) roots
+        # one trace per chunk, sampled or not, so all of a chunk's
+        # stages share its trace id and its sampling decision; bounded,
+        # oldest chunks first out.
+        self._chunk_trace: "OrderedDict[Any, SpanContext]" = OrderedDict()
+        # Traced stage instance uid -> (wall, perf) time it was queued:
+        # the ``stage:queued`` span runs from there to its lease.
+        self._queued_t: dict[int, tuple[float, float]] = {}
         self.recovered_leases = c("recovered_leases")
         self.duplicated_leases = c("duplicated_leases")
         # Per-lease attempt budget: primary uid -> distinct workers that
@@ -469,11 +477,15 @@ class Manager:
         # batch work.  The pending invariant is [deadlines ascending] +
         # [batch FIFO]; batch pushes keep their O(1) append.
         ctx = current_context()
+        if ctx is None and self.tracer is not None:
+            ctx = self._trace_ctx.get(si.uid) or self._chunk_root_locked(si)
         if ctx is not None and ctx.sampled:
             # First queueing wins: a recovery re-queue from the monitor
             # thread (no ambient context) must not clobber the request's
             # context, and neither must an unrelated caller's.
             self._trace_ctx.setdefault(si.uid, ctx)
+            if self.tracer is not None:
+                self._queued_t[si.uid] = (time.time(), time.perf_counter())
         if getattr(si, "deadline", None) is None:
             self._pending.append(si)
         else:
@@ -488,6 +500,15 @@ class Manager:
         svc = self._journal_svc()
         if svc is not None:
             svc.note_pending(si.uid)
+
+    def _chunk_root_locked(self, si: StageInstance) -> SpanContext:
+        key = si.chunk.chunk_id
+        root = self._chunk_trace.get(key)
+        if root is None:
+            root = self._chunk_trace[key] = self.tracer.start_trace()
+            if len(self._chunk_trace) > 4096:
+                self._chunk_trace.popitem(last=False)
+        return root
 
     def _pop_pending_locked(self, idx: int = 0) -> StageInstance:
         si = self._pending[idx] if idx else self._pending[0]
@@ -672,6 +693,8 @@ class Manager:
                 self._stage_done.add(si.uid)
             self._trace_ctx.pop(primary_uid, None)
             self._trace_ctx.pop(si.uid, None)
+            self._queued_t.pop(primary_uid, None)
+            self._queued_t.pop(si.uid, None)
             self._stage_outputs[primary_uid] = outputs
             for w_wid, wst in self._workers.items():
                 wst.leases.discard(si.uid)
@@ -822,6 +845,7 @@ class Manager:
                 continue
             self._quarantined[pu] = err
             self._trace_ctx.pop(pu, None)
+            self._queued_t.pop(pu, None)
             newly.append(pu)
             for i, p in enumerate(self._pending):
                 if self._clone_map().get(p.uid, p.uid) == pu:
@@ -1041,12 +1065,24 @@ class Manager:
         if svc is not None:
             svc.note_lease(si.uid, wid)
         ctx = self._trace_ctx.get(si.uid)
+        queued = self._queued_t.pop(si.uid, None)
         if ctx is not None:
             # Re-install the request's context around the dispatch: the
             # submit_stage call (direct or over a TracingBus) carries it
-            # to the worker, and the lease itself becomes a span.
+            # to the worker; the wait in the pending queue and the lease
+            # itself become spans.
             with use_context(ctx):
                 if self.tracer is not None:
+                    if queued is not None:
+                        self.tracer.record_span(
+                            "stage:queued",
+                            ctx=self.tracer.child(ctx),
+                            parent=ctx.span_id,
+                            cat="sched",
+                            ts=queued[0],
+                            dur=time.perf_counter() - queued[1],
+                            args={"uid": si.uid},
+                        )
                     with self.tracer.span(
                         "stage:lease",
                         cat="sched",

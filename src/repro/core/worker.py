@@ -73,14 +73,22 @@ def _lane_device(spec: "LaneSpec") -> Any:
     return devices[spec.index]
 
 
-def _to_host(value: Any) -> Any:
+def _to_host(value: Any) -> tuple[Any, int]:
     """Download: ``value`` with every device array replaced by its host
-    (NumPy) copy; values holding no device array pass through as is."""
+    (NumPy) copy, and the bytes that moved; values holding no device
+    array pass through as is (0 bytes).  An array whose host copy JAX
+    already holds (``_npy_value``, kept by an earlier download) moves
+    nothing: an op that passes earlier outputs through in its state
+    brings down only its new arrays."""
     import jax
 
-    if any(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(value)):
-        return jax.device_get(value)
-    return value
+    arrays = [x for x in jax.tree_util.tree_leaves(value)
+              if isinstance(x, jax.Array)]
+    if not arrays:
+        return value, 0
+    nbytes = sum(x.nbytes for x in arrays
+                 if getattr(x, "_npy_value", None) is None)
+    return jax.device_get(value), nbytes
 
 
 def _place(value: Any, device: Any) -> Any:
@@ -97,6 +105,58 @@ def _place(value: Any, device: Any) -> Any:
     return jax.tree_util.tree_map(put, value)
 
 
+def _moved(before: Any, after: Any) -> bool:
+    """Whether ``_place`` replaced any leaf of ``before``."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves
+    return any(a is not b for a, b in zip(leaves(before), leaves(after)))
+
+
+#: The phases of a lane thread, in the order an op passes through them:
+#: waiting for a ready op, gathering its inputs, the implementation call
+#: (host Python, the op's own uploads, the enqueue), waiting on the
+#: device for its outputs, downloading them, and the commit with its
+#: callbacks into the Manager.  Together they tile the thread's time.
+LANE_PHASES = ("wait", "gather", "dispatch", "sync", "d2h", "commit")
+
+
+class _PhaseClock:
+    """Splits one lane thread's time into :data:`LANE_PHASES`.
+
+    ``lap(phase)`` charges the time since the previous lap to the
+    always-on counter ``worker.lane.<lane>.<phase>_ns``, so the counters
+    sum to the thread's life whether or not a tracer is attached.
+    """
+
+    __slots__ = ("ns", "t", "wall_offset_ns")
+
+    def __init__(self, metrics: MetricsRegistry, lane: str) -> None:
+        self.ns = {p: metrics.counter(f"worker.lane.{lane}.{p}_ns")
+                   for p in LANE_PHASES}
+        self.restart()
+
+    def restart(self) -> None:
+        self.t = time.perf_counter_ns()
+        self.anchor()
+
+    def anchor(self) -> None:
+        """Spans need wall time: take the wall clock's offset from
+        perf_counter anew (a stepped wall clock moves it)."""
+        self.wall_offset_ns = time.time_ns() - time.perf_counter_ns()
+
+    def lap(self, phase: str) -> tuple[int, int]:
+        """Close ``phase`` now; returns its ``(start, end)`` in
+        ``perf_counter_ns`` time."""
+        now = time.perf_counter_ns()
+        start, self.t = self.t, now
+        self.ns[phase].inc(now - start)
+        return start, now
+
+    def busy_seconds(self) -> float:
+        return sum(int(c) for p, c in self.ns.items() if p != "wait") * 1e-9
+
+
 class DeviceMemory:
     """LRU store emulating an accelerator's discrete memory.
 
@@ -109,8 +169,6 @@ class DeviceMemory:
     def __init__(self, slots: int = 64):
         self.slots = slots
         self._store: "OrderedDict[int, Any]" = OrderedDict()
-        self.uploads = 0
-        self.downloads = 0
         self.evictions = 0
 
     def put(self, uid: int, value: Any) -> list[tuple[int, Any]]:
@@ -161,10 +219,13 @@ class OpContext:
 @dataclass
 class _LaneState:
     spec: LaneSpec
+    clock: _PhaseClock
     thread: Optional[threading.Thread] = None
     memory: Optional[DeviceMemory] = None
     device: Any = None  # accelerator lanes: the jax device they drive
-    busy_seconds: float = 0.0
+    # Root of the lane's own trace, under which its ``lane:wait`` spans
+    # record (the other phases record under their stage's context).
+    trace_root: Optional[SpanContext] = None
     executed: int = 0
     busy: bool = False  # currently executing (work-conserving batching)
     # Prefetch double-buffer: next tuple whose inputs are being uploaded.
@@ -231,6 +292,7 @@ class WorkerRuntime:
         self._lanes = [
             _LaneState(
                 spec=s,
+                clock=_PhaseClock(self.metrics, f"{s.kind}{s.index}"),
                 memory=DeviceMemory(s.memory_slots) if s.kind != HOST_KIND else None,
             )
             for s in lanes
@@ -316,6 +378,12 @@ class WorkerRuntime:
         # op run by (op name, implementation kind).
         self.host_fallbacks = c("host_fallbacks")
         self.variant_runs: dict[tuple[str, str], int] = {}
+        # Host<->device traffic of the accelerator lanes, as it moves:
+        # every download passes through ``_download``, and an upload is
+        # a ``_place`` that moved an array between devices.
+        self.d2h_bytes = c("d2h_bytes")
+        self.d2h_calls = c("d2h_calls")
+        self.uploads = c("uploads")
         # Trace context per leased stage: captured at submit time (the
         # TracingBus installs the sender's context around the handler)
         # and re-installed around op execution and the completion
@@ -665,15 +733,12 @@ class WorkerRuntime:
             "reuse_hits": int(self.scheduler.stats.reuse_hits),
             "reuse_misses": int(self.scheduler.stats.reuse_misses),
             "lane_busy": {
-                f"{l.spec.kind}{l.spec.index}": l.busy_seconds for l in self._lanes
+                f"{l.spec.kind}{l.spec.index}": l.clock.busy_seconds()
+                for l in self._lanes
             },
             "executed": sum(l.executed for l in self._lanes),
-            "uploads": sum(
-                l.memory.uploads for l in self._lanes if l.memory is not None
-            ),
-            "downloads": sum(
-                l.memory.downloads for l in self._lanes if l.memory is not None
-            ),
+            "uploads": int(self.uploads),
+            "downloads": int(self.d2h_calls),
             "device_evictions": sum(
                 l.memory.evictions for l in self._lanes if l.memory is not None
             ),
@@ -714,12 +779,14 @@ class WorkerRuntime:
             self._serve_lane(lane)
 
     def _serve_lane(self, lane: _LaneState) -> None:
+        lane.clock.restart()
         while True:
             with self._lock:
                 lane.busy = False
                 while not self._stop and not self.scheduler:
                     self._work_ready.wait(timeout=0.25)
                 if self._stop:
+                    lane.clock.lap("wait")
                     return
                 resident = (
                     lane.memory.resident_uids()
@@ -793,10 +860,18 @@ class WorkerRuntime:
 
     def _run_batch(self, lane: _LaneState, ois: list[OperationInstance]) -> None:
         """Execute one dispatch decision: a single op or a micro-batch
-        of same-op instances (one batched call, amortized launch)."""
+        of same-op instances (one batched call, amortized launch).
+
+        The lane's clock laps at every phase boundary (``LANE_PHASES``).
+        An op's time, which the controllers read, runs from its gather
+        to the end of its sync: on an accelerator lane the outputs that
+        ``_commit`` writes back to the host are waited on first, so the
+        device time is the op's own and not that of whatever blocks
+        next.  A chained output is not waited on (its op's clock stops
+        at the enqueue)."""
+        clock = lane.clock
+        wait = clock.lap("wait")
         var = self.registry.get(ois[0].op.variant_name)
-        ts_wall = time.time()
-        t0 = time.perf_counter()
         ctxs = [
             OpContext(
                 chunk=oi.chunk,
@@ -805,6 +880,7 @@ class WorkerRuntime:
             )
             for oi in ois
         ]
+        gather = clock.lap("gather")
         batch_fn = (
             var.batch_implementation(lane.spec.kind) if len(ois) > 1 else None
         )
@@ -840,8 +916,10 @@ class WorkerRuntime:
                     pairs.append((oi, impl(ctx)))
                 except BaseException as exc:  # noqa: BLE001 - recorded
                     failures.append((oi, exc))
-        elapsed = time.perf_counter() - t0
-        lane.busy_seconds += elapsed
+        dispatch = clock.lap("dispatch")
+        synced = self._sync(lane, pairs)
+        sync = clock.lap("sync")
+        elapsed = (sync[1] - gather[0]) * 1e-9
         lane.executed += len(ois)
         self.op_runtime_hist.observe(elapsed / len(ois))
         with self._lock:
@@ -851,31 +929,35 @@ class WorkerRuntime:
                 self._stage_exec[suid] = (
                     self._stage_exec.get(suid, 0.0) + per_op
                 )
-        if self.tracer is not None:
-            # One span per op instance (batch-mates share ts/dur): each
-            # chains under its own stage's context so a request timeline
-            # shows exactly which lane ran which op, and when.  The ctx
-            # tag was written by submit_stage under the worker lock
+        # The tracer is read once: a span set is all or nothing per batch.
+        tracer = self.tracer
+        if tracer is not None:
+            # One span per phase per op instance (batch-mates share the
+            # times), each under its own stage's context, on the lane's
+            # row; the wait before it under the lane's own root.  The
+            # ctx tag was written by submit_stage under the worker lock
             # before the op could queue, so the lock-free read here is
             # safe; unsampled ops carry no tag and cost one getattr.
-            tid = None
+            if lane.trace_root is None:
+                lane.trace_root = tracer.start_trace()
+            clock.anchor()
+            self._lane_span(tracer, lane, "lane:wait", lane.trace_root, wait)
             for oi in ois:
                 sctx = getattr(oi, "_trace_ctx", None)
                 if sctx is None:
                     continue
-                if tid is None:
-                    tid = f"{lane.spec.kind}{lane.spec.index}"
-                sub = self.tracer.child(sctx)
-                self.tracer.record_span(
-                    f"op:{oi.op.name}",
-                    ctx=sub,
-                    parent=sctx.span_id,
+                uid = {"uid": oi.uid}
+                self._lane_span(tracer, lane, "lane:gather", sctx, gather,
+                                args=uid)
+                self._lane_span(
+                    tracer, lane, f"op:{oi.op.name}", sctx, dispatch,
                     cat="op",
-                    ts=ts_wall,
-                    dur=elapsed,
-                    tid=tid,
-                    args={"uid": oi.uid, "batch": len(ois)},
+                    args={"uid": oi.uid, "batch": len(ois),
+                          "synced": oi.uid in synced},
                 )
+                if oi.uid in synced:
+                    self._lane_span(tracer, lane, "lane:sync", sctx, sync,
+                                    args=uid)
         if self.observe_runtimes:
             var.observe_runtime(lane.spec.kind, elapsed / len(ois))
             if self.scheduler.policy == "pats":
@@ -890,9 +972,74 @@ class WorkerRuntime:
                     self._reorder_est[var.name] = est
                     with self._lock:
                         self.scheduler.reestimate(self._estimate_of)
+        start = sync[1]
         for oi, out in pairs:
-            self._commit(lane, oi, out)
+            d2h = self._commit(lane, oi, out)
+            commit = (start, clock.lap("commit")[1])
+            start = commit[1]
+            sctx = getattr(oi, "_trace_ctx", None)
+            if tracer is not None and sctx is not None:
+                parent = self._lane_span(
+                    tracer, lane, "lane:commit", sctx, commit,
+                    args={"uid": oi.uid},
+                )
+                if d2h is not None and parent is not None:
+                    self._lane_span(
+                        tracer, lane, "lane:d2h", parent, d2h[:2],
+                        args={"uid": oi.uid, "bytes": d2h[2]},
+                    )
         self._record_failures(failures)
+
+    def _sync(
+        self, lane: _LaneState, pairs: list[tuple[OperationInstance, Any]]
+    ) -> set[int]:
+        """Wait on the device for the outputs that ``_commit`` will write
+        back to the host (all of an accelerator lane's outputs but the
+        chained ones), before the worker lock is taken: the download
+        that follows only copies.  Returns the uids waited on."""
+        if lane.memory is None or not pairs:
+            return set()
+        if self.chaining:
+            with self._lock:
+                pairs = [
+                    (oi, out) for oi, out in pairs
+                    if not self._chainable_locked(oi)
+                ]
+            if not pairs:
+                return set()
+        import jax
+
+        jax.block_until_ready([out for _, out in pairs])
+        return {oi.uid for oi, _ in pairs}
+
+    def _lane_span(
+        self,
+        tracer: Any,
+        lane: _LaneState,
+        name: str,
+        parent: SpanContext,
+        interval: tuple[int, int],
+        *,
+        cat: str = "lane",
+        args: Optional[dict[str, Any]] = None,
+    ) -> Optional[SpanContext]:
+        """Record one phase of ``lane`` (an interval of its clock) as a
+        span under ``parent``; returns the span's context."""
+        if not parent.sampled:
+            return None
+        sub = tracer.child(parent)
+        start, end = interval
+        tracer.record_span(
+            name,
+            ctx=sub,
+            parent=parent.span_id,
+            cat=cat,
+            ts=(start + lane.clock.wall_offset_ns) * 1e-9,
+            dur=(end - start) * 1e-9,
+            tid=f"{lane.spec.kind}{lane.spec.index}",
+            args=args,
+        )
+        return sub
 
     def _hook_op_start(self, oi: OperationInstance) -> None:
         hook = self.on_op_start
@@ -1012,10 +1159,10 @@ class WorkerRuntime:
                 name = self._dep_name(oi, uid)
                 if lane.memory is not None:
                     if uid not in lane.memory:
-                        lane.memory.uploads += 1
-                        self._device_put_locked(
-                            lane, uid, _place(value, lane.device)
-                        )
+                        placed = _place(value, lane.device)
+                        if _moved(value, placed):
+                            self.uploads += 1
+                        self._device_put_locked(lane, uid, placed)
                     inputs[name] = lane.memory.get(uid)
                 else:
                     inputs[name] = value
@@ -1027,9 +1174,8 @@ class WorkerRuntime:
         soft cap, never a correctness hazard)."""
         for e_uid, e_val in lane.memory.put(uid, value):
             if self._device_only.pop(e_uid, None) is not None:
-                lane.memory.downloads += 1
                 self.chain_writebacks += 1
-                self.store.put(op_key(e_uid), _to_host(e_val))
+                self.store.put(op_key(e_uid), self._download(e_val)[0])
                 # Same invariant as _commit/_materialize: keep the only
                 # host copy resident until its consumers ran.
                 self.store.pin(op_key(e_uid))
@@ -1046,9 +1192,8 @@ class WorkerRuntime:
         holder = self._device_only.get(uid)
         if holder is None or holder.memory is None or uid not in holder.memory:
             return None
-        value = _to_host(holder.memory.get(uid))
+        value = self._download(holder.memory.get(uid))[0]
         del self._device_only[uid]
-        holder.memory.downloads += 1
         self.chain_writebacks += 1
         self.store.put(op_key(uid), value)
         self.store.pin(op_key(uid))
@@ -1085,15 +1230,28 @@ class WorkerRuntime:
                 return False
         return True
 
-    def _commit(self, lane: _LaneState, oi: OperationInstance, out: Any) -> None:
+    def _download(self, value: Any) -> tuple[Any, int]:
+        """Every download of device arrays to the host passes here:
+        the host copy and the bytes that moved."""
+        host, nbytes = _to_host(value)
+        if nbytes:
+            self.d2h_bytes += nbytes
+            self.d2h_calls += 1
+        return host, nbytes
+
+    def _commit(
+        self, lane: _LaneState, oi: OperationInstance, out: Any
+    ) -> Optional[tuple[int, int, int]]:
+        """Record ``oi``'s output and release its dependents; returns the
+        lane clock's ``(start, end, bytes)`` of the output's download
+        (the ``d2h`` phase), or None when nothing was written back."""
+        d2h = None
         with self._lock:
             chained = False
             host_chained = False
             if lane.memory is not None:
                 self._device_put_locked(lane, oi.uid, out)
                 chained = self._chainable_locked(oi)
-                if not chained and not self.locality:
-                    lane.memory.downloads += 1  # basic mode: always download
             elif self.chaining and self._chainable_locked(oi):
                 # Chained CPU lane: every consumer is known locally, so
                 # the intermediate skips the region-store round-trip
@@ -1111,10 +1269,11 @@ class WorkerRuntime:
                 # Host write-back: the host tier holds host bytes, so an
                 # accelerator lane downloads here, and only its bounded
                 # DeviceMemory keeps device buffers alive.
-                self.store.put(
-                    op_key(oi.uid),
-                    _to_host(out) if lane.memory is not None else out,
-                )
+                if lane.memory is not None:
+                    lane.clock.lap("commit")
+                    out, nbytes = self._download(out)
+                    d2h = (*lane.clock.lap("d2h"), nbytes)
+                self.store.put(op_key(oi.uid), out)
                 # Keep the output resident until its consumers (and the
                 # stage-completion read below) ran: tier budgets are a
                 # soft cap for the live working set, never a correctness
@@ -1193,6 +1352,7 @@ class WorkerRuntime:
             # Manager derives from it) then carries the request's trace.
             with use_context(sctx):
                 self.on_stage_complete(si, outputs, exec_s)
+        return d2h
 
     def _maybe_unpin_locked(self, uid: int) -> None:
         """Unpin ``uid``'s output once no locally-known op still needs it."""

@@ -13,8 +13,8 @@ Design constraints, in order:
    ``stats()`` view and :meth:`MetricsRegistry.snapshot` coerces to
    plain ``int``/``float`` so any codec can carry them.
 3. **Cheap.**  An increment is one lock acquire + one integer add;
-   the overhead guard in ``tests/test_telemetry.py`` and the ≤2%
-   budget in ``BENCH_PR8.json`` keep it honest.
+   the overhead guard in ``tests/test_telemetry.py`` keeps it
+   honest.
 
 No label dimensions: components that need per-instance metrics (one
 worker vs another) hold per-instance *registries* — the Manager-side

@@ -35,7 +35,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Any, Callable, Iterator, Optional
 
-from ..transport.bus import Handler, MessageBus, Peer
+from ..transport.bus import TRACE_ENVELOPE, Handler, MessageBus, Peer
 
 __all__ = [
     "SpanContext",
@@ -62,7 +62,7 @@ UNTRACED_METHODS = frozenset(
 SPAN_KEYS = ("name", "cat", "trace", "span", "parent", "service", "ts",
              "dur", "tid", "args")
 
-_ENVELOPE = "__trace__"
+_ENVELOPE = TRACE_ENVELOPE
 
 _tls = threading.local()
 
@@ -154,6 +154,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: deque[dict[str, Any]] = deque(maxlen=capacity)
         self.spans_recorded = 0
+        # Spans pushed out of the full buffer: a reader of a window
+        # trusts the buffer only while this stays put.
+        self.spans_dropped = 0
         self.traces_started = 0
         self.traces_sampled = 0
 
@@ -208,6 +211,8 @@ class Tracer:
             "args": dict(args) if args else {},
         }
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.spans_dropped += 1
             self._spans.append(span)
             self.spans_recorded += 1
         if self.recorder is not None:
@@ -265,6 +270,7 @@ class Tracer:
             return {
                 "spans_recorded": self.spans_recorded,
                 "spans_buffered": len(self._spans),
+                "spans_dropped": self.spans_dropped,
                 "traces_started": self.traces_started,
                 "traces_sampled": self.traces_sampled,
             }
@@ -399,6 +405,7 @@ class TracingBus(MessageBus):
                     ):
                         return h(wrapped, inner)
 
+            handle.traced = True  # type: ignore[attr-defined]
             return handle
 
         return {m: bind(m, h) for m, h in handlers.items()}

@@ -22,7 +22,9 @@ The contract both provide:
   other; a server learns of new peers via ``on_connect``.
 
 Handlers have signature ``handler(peer, payload) -> result``; the
-result travels back as the reply (requests only).
+result travels back as the reply (requests only).  A sender that traces
+wraps a payload in a trace envelope (``repro.telemetry.TracingBus``); a
+handler that does not trace gets the payload without it.
 """
 
 from __future__ import annotations
@@ -47,6 +49,22 @@ Handler = Callable[["Peer", Any], Any]
 #: message that was split so bulk region payloads cannot head-of-line
 #: block control traffic sharing the connection.
 REQ, REP, ERR, NTF, SEG = "req", "rep", "err", "ntf", "seg"
+
+#: Key of the trace envelope ``{TRACE_ENVELOPE: context, "p": payload}``.
+TRACE_ENVELOPE = "__trace__"
+
+
+def handle(handler: Handler, peer: "Peer", payload: Any) -> Any:
+    """Invoke ``handler``; one that does not read trace envelopes (no
+    ``traced`` attribute) gets the payload out of its envelope, so an
+    untraced receiver ignores the context a traced sender adds."""
+    if (
+        isinstance(payload, dict)
+        and TRACE_ENVELOPE in payload
+        and not getattr(handler, "traced", False)
+    ):
+        payload = payload.get("p")
+    return handler(peer, payload)
 
 
 class BusError(RuntimeError):

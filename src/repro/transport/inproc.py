@@ -15,7 +15,9 @@ import itertools
 import threading
 from typing import Any, Callable, Optional
 
-from .bus import BusClosedError, BusError, Handler, MessageBus, Peer, RemoteError
+from .bus import (
+    BusClosedError, BusError, Handler, MessageBus, Peer, RemoteError, handle,
+)
 
 __all__ = ["InprocBus"]
 
@@ -42,7 +44,7 @@ class _InprocPeer(Peer):
         # Handler failures surface as RemoteError on every backend: code
         # written against InprocBus keeps working over SocketBus.
         try:
-            return handler(other, payload)
+            return handle(handler, other, payload)
         except BusError:
             raise
         except BaseException as exc:  # noqa: BLE001 - mirrored to caller

@@ -54,6 +54,7 @@ from .bus import (
     MessageBus,
     Peer,
     RemoteError,
+    handle,
 )
 from .codec import WireCodec, default_codec
 from ..staging.tiers import sizeof as _sizeof
@@ -334,7 +335,7 @@ class SocketPeer(Peer):
             try:
                 if handler is None:
                     raise KeyError(f"no handler for {method!r}")
-                result = handler(self, payload)
+                result = handle(handler, self, payload)
                 if kind == REQ:
                     self._reply(REP, msg_id, method, result)
             except BaseException as exc:  # noqa: BLE001 - sent to caller

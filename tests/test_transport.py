@@ -97,6 +97,35 @@ def test_bus_call_notify_and_remote_error(bus_cls):
     server.close()
 
 
+@pytest.mark.parametrize("bus_cls", [T.InprocBus, T.SocketBus])
+def test_untraced_handler_ignores_a_trace_envelope(bus_cls):
+    """A traced sender's envelope reaches a handler that does not trace
+    as the bare payload; one marked ``traced`` sees the envelope."""
+    from repro.transport.bus import TRACE_ENVELOPE
+
+    seen: list = []
+
+    def plain(peer, payload):
+        seen.append(payload)
+        return payload
+
+    def traced(peer, payload):
+        seen.append(payload)
+        return "ok"
+
+    traced.traced = True
+    server = bus_cls()
+    address = server.serve({"plain": plain, "traced": traced})
+    client = bus_cls() if bus_cls is T.SocketBus else server
+    peer = client.connect(address)
+    env = {TRACE_ENVELOPE: {"t": "a" * 16, "s": "b" * 16}, "p": {"x": 1}}
+    assert peer.call("plain", env) == {"x": 1}
+    assert peer.call("traced", env) == "ok"
+    assert seen == [{"x": 1}, env]
+    peer.close()
+    server.close()
+
+
 def test_socketbus_ordered_delivery_and_coalescing():
     received: list[int] = []
     release = threading.Event()
