@@ -9,10 +9,10 @@ lanes -> function variants, and ``RequestGateway`` in front for serving —
 in this one process, which is the only one that touches the chip.
 
 * **A, batch.** Generated 4096² tiles through one worker with a single
-  ``tpu`` lane and the fused workflow, so the Pallas ``recon_to_nuclei``
-  and ``feature_fused`` variants run.  Prints each tile's object count
-  and the device's memory after it, which must level off: more tiles
-  than the chip could hold at once pass through.
+  ``tpu`` lane and the fused workflow, so the Pallas ``recon_to_nuclei``,
+  ``fill_holes`` and ``feature_fused`` variants run.  Prints each tile's
+  object count and the device's memory after it, which must level off:
+  more tiles than the chip could hold at once pass through.
 * **B, reference.** 1024² tiles through the same lane, unfused (the
   Pallas ``color_deconv`` variant) and fused, each compared with the
   NumPy CPU variants (``run_tile(tile, "cpu")``).
@@ -52,6 +52,7 @@ LANE = "tpu"
 #: The Pallas kernel variant each of these ops binds on ``tpu`` lanes.
 PALLAS = {
     "recon_to_nuclei": "recon_pallas",
+    "fill_holes": "fill_holes_pallas",
     "feature_fused": "feature_fused_pallas",
     "color_deconv": "color_deconv_pallas",
 }
@@ -271,7 +272,7 @@ def phase_batch(sizes: Sizes, seed: int, device, failures) -> None:
         failures.append(f"A: {len(tiles) - good} tiles with n_objects "
                         f"outside (0, MAX_OBJECTS): {counts}")
     runs = run.check_variants(reg, failures, "A")
-    for op in ("recon_to_nuclei", "feature_fused"):
+    for op in ("recon_to_nuclei", "fill_holes", "feature_fused"):
         if not pallas_ran(reg, runs, op):
             failures.append(f"A: the Pallas {op} variant never ran")
     peaks = [p for _, _, p in memory if p is not None]

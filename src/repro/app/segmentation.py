@@ -357,15 +357,23 @@ def area_threshold_accel(state: dict, min_area: int = 24, max_area: int = 8192) 
     return {**state, "mask_at": mask}
 
 
-@jax.jit
-def _fill_holes_accel(mask: jnp.ndarray):
+def _fill_holes_j(mask: jnp.ndarray, reconstruct) -> jnp.ndarray:
+    """Fill the holes of ``mask``: reconstruct the background (255) from
+    the image border with ``reconstruct(marker, mask)``; background the
+    border cannot reach is a hole.  Any implementation of the 8-connected
+    geodesic reconstruction gives the same mask (the fixpoint is unique)."""
     inv = (~mask).astype(jnp.float32) * 255.0
     h, w = mask.shape
     border = jnp.zeros((h, w), jnp.float32)
     border = border.at[0, :].set(255.0).at[-1, :].set(255.0)
     border = border.at[:, 0].set(255.0).at[:, -1].set(255.0)
-    recon = _recon_j(jnp.minimum(border, inv), inv)
+    recon = reconstruct(jnp.minimum(border, inv), inv)
     return mask | (recon == 0)
+
+
+@jax.jit
+def _fill_holes_accel(mask: jnp.ndarray):
+    return _fill_holes_j(mask, _recon_j)
 
 
 def fill_holes_accel(state: dict) -> dict:

@@ -141,6 +141,7 @@ def test_pallas_tpu_variants_registered_and_correct():
     register_variants(reg, with_pallas=True)
     assert reg.get("color_deconv").supports("tpu")
     assert reg.get("recon_to_nuclei").supports("tpu")
+    assert reg.get("fill_holes").supports("tpu")
 
     tile = synth_tile(2, size=128, seed=9)
     state = morph_open_cpu(rbc_detection_cpu(tile))
@@ -153,10 +154,18 @@ def test_pallas_tpu_variants_registered_and_correct():
     agree = (np.asarray(got["nuclei"]) == np.asarray(want["nuclei"])).mean()
     assert agree > 0.999
 
+    # fill_holes: the Pallas program gives the cpu variant's mask exactly
+    at = OP_IMPLS["area_threshold"][0](want)
+    ctx_fh = OpContext(chunk=chunk, inputs={"area_threshold": at},
+                       lane_kind="tpu")
+    got_fh = reg.get("fill_holes").implementation("tpu")(ctx_fh)
+    np.testing.assert_array_equal(
+        np.asarray(got_fh["mask"]), OP_IMPLS["fill_holes"][0](at)["mask"]
+    )
+
     # color_deconv: hema plane matches to fp tolerance
-    state2 = want
-    for name in ("area_threshold", "fill_holes", "pre_watershed",
-                 "watershed", "bwlabel"):
+    state2 = at
+    for name in ("fill_holes", "pre_watershed", "watershed", "bwlabel"):
         state2 = OP_IMPLS[name][0](state2)
     ctx2 = OpContext(chunk=chunk, inputs={"bwlabel": state2}, lane_kind="tpu")
     got2 = reg.get("color_deconv").implementation("tpu")(ctx2)
@@ -165,3 +174,60 @@ def test_pallas_tpu_variants_registered_and_correct():
         np.asarray(got2["hema"]), np.asarray(want2["hema"]),
         rtol=5e-4, atol=5e-4,
     )
+
+
+def _ring(mask, top, left, side):
+    """Draw a closed one-pixel square ring; return its interior."""
+    bottom, right = top + side - 1, left + side - 1
+    mask[top, left:right + 1] = mask[bottom, left:right + 1] = True
+    mask[top:bottom + 1, left] = mask[top:bottom + 1, right] = True
+    hole = np.zeros_like(mask)
+    hole[top + 1:bottom, left + 1:right] = True
+    return hole
+
+
+def _holes_case(case, shape):
+    """A hand-made mask and the holes ``fill_holes`` must fill in it."""
+    h, w = shape
+    mask = np.zeros(shape, bool)
+    none = np.zeros(shape, bool)
+    if case == "closed_ring":
+        return mask, _ring(mask, h // 4, w // 4, h // 2)
+    if case == "c_open_to_border":
+        # A ring whose right side is cut by a corridor to the border.
+        _ring(mask, h // 4, w // 4, h // 2)
+        mask[h // 2 - 2:h // 2 + 2, w // 4 + h // 2 - 1:] = False
+        return mask, none
+    if case == "diagonal_leak":
+        # Without its corner pixel, the interior meets the outside only
+        # across a diagonal: background is 8-connected, so no hole.
+        _ring(mask, h // 4, w // 4, h // 2)
+        mask[h // 4, w // 4] = False
+        return mask, none
+    if case == "touching_border":
+        # Rings on the top and left edges of the tile and a band down
+        # the right edge: the border pixels are foreground.
+        hole = _ring(mask, 0, w // 2, 16) | _ring(mask, h // 2, 0, 12)
+        mask[:, -3:] = True
+        return mask, hole
+    if case == "all_foreground":
+        return np.ones(shape, bool), none
+    assert case == "all_background"
+    return mask, none
+
+
+@pytest.mark.parametrize("shape", [(128, 128), (256, 384)])
+@pytest.mark.parametrize("case", ["closed_ring", "c_open_to_border",
+                                  "diagonal_leak", "touching_border",
+                                  "all_foreground", "all_background"])
+def test_fill_holes_pallas_program_equals_xla(case, shape):
+    """The Pallas ``fill_holes`` program (interpret mode here) returns
+    exactly the XLA program's mask, and both fill what they must."""
+    from repro.app.pipeline import _fill_holes_accel_pallas
+    from repro.app.segmentation import _fill_holes_accel
+
+    mask, holes = _holes_case(case, shape)
+    want = np.asarray(_fill_holes_accel(mask))
+    np.testing.assert_array_equal(want, mask | holes)
+    np.testing.assert_array_equal(np.asarray(_fill_holes_accel_pallas(mask)),
+                                  want)

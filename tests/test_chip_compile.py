@@ -60,3 +60,17 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     plane = jax.ShapeDtypeStruct((SIDE, SIDE), jnp.float32, sharding=one_chip)
     compiled = jax.jit(fn).lower(*([plane] * n_planes)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fill_holes_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The ``tpu`` variant's whole ``fill_holes`` program, with the Pallas
+    reconstruction compiled, not interpreted, inside it."""
+    from repro.app.pipeline import _fill_holes_accel_pallas
+    from repro.kernels import ops
+
+    # The CPU backend is the default here: steer the kernel wrapper to
+    # the compiled kernel the chip would run.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    plane = jax.ShapeDtypeStruct((SIDE, SIDE), jnp.bool_, sharding=one_chip)
+    compiled = _fill_holes_accel_pallas.lower(plane).compile()
+    assert "tpu_custom_call" in compiled.as_text()
