@@ -123,7 +123,7 @@ class Run:
     """One workflow run through a Manager over one fresh worker."""
 
     def __init__(self, tiles, *, lanes, fused, reg, failures, name,
-                 on_feature_done=None, window=4):
+                 on_feature_done=None):
         from repro.app import build_workflow
         from repro.core import (
             ConcreteWorkflow, DataChunk, LaneSpec, Manager, ManagerConfig,
@@ -139,7 +139,7 @@ class Run:
             variant_registry=reg,
         )
         mgr = Manager(cw, ManagerConfig(
-            window=window, heartbeat_timeout=600.0, backup_tasks=False,
+            heartbeat_timeout=600.0, backup_tasks=False,
         ))
         by_uid = {si.uid: si for si in cw.stage_instances.values()}
         if on_feature_done is not None:
@@ -407,8 +407,7 @@ def run_four_chips(sizes: Sizes, seed: int, chips: int) -> list[str]:
         produced.clear()
         t0 = time.perf_counter()
         runs[n] = Run(tiles, lanes=[(LANE, i) for i in range(n)], fused=True,
-                      reg=reg, failures=failures, name=f"lanes{n}",
-                      window=2 * n)
+                      reg=reg, failures=failures, name=f"lanes{n}")
         log(f"lanes{n} wall_s={time.perf_counter() - t0:.3f} "
             f"tiles={len(tiles)} lane_busy={runs[n].stats['lane_busy']}")
         runs[n].check_variants(reg, failures, f"lanes{n}")

@@ -4,7 +4,9 @@ The Manager has the overall view of the runtime: it instantiates the
 abstract workflow, tracks inter-stage dependencies, and leases stage
 instances to Workers demand-driven — each Worker holds at most
 ``window`` leases and requests more as leases complete (the paper's
-*Window size*, §V-F).
+*Window size*, §V-F).  Left unset, a worker's window follows its lanes:
+:data:`LEASES_PER_LANE` leases a lane, and never fewer than
+:data:`MIN_WINDOW`.
 
 Beyond the paper, the Manager provides the fault-tolerance required for
 thousand-node deployments:
@@ -49,10 +51,17 @@ from ..staging.tiers import RegionKey, sizeof
 
 __all__ = ["Manager", "ManagerConfig"]
 
+#: The window of a worker whose ``ManagerConfig.window`` is unset: two
+#: leases a lane, so a lane can take another tile's op while the lease
+#: it just finished is handed back, and never fewer than four.
+LEASES_PER_LANE = 2
+MIN_WINDOW = 4
+
 
 @dataclass
 class ManagerConfig:
-    window: int = 4                  # leases in flight per worker
+    # Leases in flight per worker; None = derived from its lanes.
+    window: Optional[int] = None
     heartbeat_timeout: float = 60.0  # seconds without progress => dead
     backup_tasks: bool = True       # duplicate tail leases
     poll_interval: float = 0.01
@@ -182,6 +191,7 @@ class HealthScorer:
 @dataclass
 class _WorkerState:
     runtime: WorkerRuntime
+    window: int                  # its lease window (Manager.window_of)
     leases: set[int] = field(default_factory=set)
     last_heartbeat: float = field(default_factory=time.monotonic)
     dead: bool = False
@@ -388,7 +398,9 @@ class Manager:
                 # Pushes racing toward the dead incarnation are void:
                 # release their reserved ingress bytes.
                 self._abort_push_target_locked(wid)
-            self._workers[wid] = _WorkerState(runtime=runtime)
+            self._workers[wid] = _WorkerState(
+                runtime=runtime, window=self.window_of(runtime)
+            )
             if address is not None:
                 # Data-plane address: lets sibling workers dial this one
                 # for region bytes instead of relaying through here.
@@ -400,6 +412,14 @@ class Manager:
             self._dispatch_all_locked()
             self._check_done_locked()
         self._fire_failure_hooks(newly_q)
+
+    def window_of(self, runtime: Any) -> int:
+        """The lease window of ``runtime``: the configured one, else
+        derived from its ``lane_count`` (a bus-mode proxy carries the
+        one its worker reported when it registered)."""
+        if self.cfg.window is not None:
+            return self.cfg.window
+        return max(MIN_WINDOW, LEASES_PER_LANE * runtime.lane_count)
 
     def _heartbeat(self, worker_id: int) -> None:
         with self._lock:
@@ -1034,10 +1054,10 @@ class Manager:
         fast completion into a slow one — worst at the tail, where one
         probe lease can hold the whole run hostage until a hedge fires."""
         if not self.cfg.health_scoring:
-            return self.cfg.window
+            return st.window
         if st.probation:
             healthy_slack = sum(
-                max(self.cfg.window - len(ws.leases), 0)
+                max(ws.window - len(ws.leases), 0)
                 for w2, ws in self._workers.items()
                 if w2 != wid
                 and not ws.dead
@@ -1046,7 +1066,7 @@ class Manager:
             )
             return 1 if len(self._pending) > healthy_slack else 0
         w = self.health.weight(wid, self.cfg.heartbeat_timeout)
-        return max(1, int(self.cfg.window * w + 1e-9))
+        return max(1, int(st.window * w + 1e-9))
 
     def _lease_locked(
         self, wid: int, st: _WorkerState, si: StageInstance
@@ -1472,7 +1492,7 @@ class Manager:
             if not st.dead and st.runtime.alive
         }
         slots = {
-            wid: max(self.cfg.window - len(st.leases), 0)
+            wid: max(st.window - len(st.leases), 0)
             for wid, st in live.items()
         }
         out: dict[int, int] = {}

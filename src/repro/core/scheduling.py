@@ -19,6 +19,11 @@ already resident in that accelerator's memory.  When speedups are
 known, the dependent wins only if ``S_d >= S_q * (1 - transferImpact)``
 where ``S_q`` is the best non-resident candidate and ``transferImpact``
 is the fraction of that candidate's execution time spent moving data.
+A worker with several accelerator lanes of one kind also tells the pop
+what its other lanes hold and run: a lane that reuses nothing of its
+own takes an op that no other lane holds the inputs of, else one whose
+holders are all busy with other ops, and otherwise leaves the ops to
+their idle holders, so a chain stays on the chip that holds it.
 
 Two extensions for the device-resident fast path:
 
@@ -56,7 +61,7 @@ import bisect
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Iterable, Optional
+from typing import Callable, Container, Iterable, Optional
 
 from .workflow import OperationInstance
 
@@ -81,6 +86,10 @@ class SchedulerStats:
     # Slack-band hybrid: pops where deadline work was queued but had
     # enough slack that the locality/PATS order was served instead.
     slack_deferrals: int = 0
+    # DL across several accelerator lanes: pops that left every ready
+    # op to the idle lane holding its inputs (a hand-off the DL let
+    # through, to a busy holder's op, counts as a reuse miss).
+    reuse_deferrals: int = 0
 
     def record(self, op_name: str, lane_kind: str) -> None:
         key = (op_name, lane_kind)
@@ -110,8 +119,9 @@ class SchedulerStats:
         per-node schedulers inside a simulation) nothing changes and
         increments stay plain-int cheap.
         """
-        for name in ("reuse_hits", "reuse_misses", "batches",
-                     "batched_ops", "deadline_pops", "slack_deferrals"):
+        for name in ("reuse_hits", "reuse_misses", "reuse_deferrals",
+                     "batches", "batched_ops", "deadline_pops",
+                     "slack_deferrals"):
             cell = registry.counter(f"{prefix}.{name}")
             cell.inc(int(getattr(self, name)))
             setattr(self, name, cell)
@@ -272,11 +282,17 @@ class ReadyScheduler:
         self,
         lane_kind: str,
         resident_producers: Optional[set[int]] = None,
+        other_lanes: Optional[list[tuple[Container[int], set[int]]]] = None,
     ) -> Optional[OperationInstance]:
         """Select the next tuple for an idle lane of ``lane_kind``.
 
         ``resident_producers`` — uids of op instances whose outputs are
         already in this lane's device memory (accelerator lanes only).
+        ``other_lanes`` — for a lane with sibling accelerator lanes of
+        its kind, each sibling's ``(resident uids, uids it is running)``
+        (nothing running when idle).  With them the pop may return
+        ``None`` while ops are queued: each is left to an idle sibling
+        that holds its inputs.
         """
         if not self:
             return None
@@ -303,7 +319,10 @@ class ReadyScheduler:
                 return task
             self.stats.slack_deferrals += 1
         if self.locality and lane_kind != HOST_KIND and resident_producers:
-            task = self._pop_locality(lane_kind, resident_producers)
+            task = self._pop_locality(lane_kind, resident_producers,
+                                      other_lanes)
+        elif self.locality and lane_kind != HOST_KIND and other_lanes:
+            task = self._pop_unowned(lane_kind, other_lanes)
         elif self.policy == "pats":
             task = (
                 self._sorted.pop_min()
@@ -330,6 +349,7 @@ class ReadyScheduler:
         self,
         lane_kind: str,
         resident_producers: Optional[set[int]] = None,
+        other_lanes: Optional[list[tuple[Container[int], set[int]]]] = None,
         *,
         limit: int = 1,
         batchable: Optional[Callable[[OperationInstance], int]] = None,
@@ -348,7 +368,7 @@ class ReadyScheduler:
         implementation must never receive more contexts than its
         declared maximum.
         """
-        first = self.pop(lane_kind, resident_producers)
+        first = self.pop(lane_kind, resident_producers, other_lanes)
         if first is None:
             return []
         batch = [first]
@@ -414,11 +434,16 @@ class ReadyScheduler:
         )
 
     def _pop_locality(
-        self, lane_kind: str, resident: set[int]
+        self,
+        lane_kind: str,
+        resident: set[int],
+        other_lanes: Optional[list[tuple[Container[int], set[int]]]] = None,
     ) -> Optional[OperationInstance]:
         pool = list(self._sorted) if self.policy == "pats" else list(self._fifo)
         reusing = [t for t in pool if t.deps & resident]
         if not reusing:
+            if other_lanes:
+                return self._pop_unowned(lane_kind, other_lanes)
             self.stats.reuse_misses += 1
             return self._pop_plain(lane_kind)
         if self.policy == "fcfs" or not self.speedups_known:
@@ -444,6 +469,37 @@ class ReadyScheduler:
         self._remove(best_q)
         self.stats.reuse_misses += 1
         return best_q
+
+    def _pop_unowned(
+        self,
+        lane_kind: str,
+        other_lanes: list[tuple[Container[int], set[int]]],
+    ) -> Optional[OperationInstance]:
+        """DL across a worker's accelerator lanes, for a lane that reuses
+        nothing: in policy order, the first op no sibling holds an input
+        of; else the first whose holders all run other ops (a hand-off,
+        counted as a miss); else ``None``, the ops left to their idle
+        holders (a deferral)."""
+        # An accelerator takes the highest speedup first under PATS.
+        pool = (list(self._sorted)[::-1] if self.policy == "pats"
+                else list(self._fifo))
+        handoff = None
+        for task in pool:
+            holders = [running for resident, running in other_lanes
+                       if any(uid in resident for uid in task.deps)]
+            if not holders:
+                self._remove(task)
+                return task
+            if handoff is None and all(
+                running and not (running & task.deps) for running in holders
+            ):
+                handoff = task
+        if handoff is None:
+            self.stats.reuse_deferrals += 1
+            return None
+        self._remove(handoff)
+        self.stats.reuse_misses += 1
+        return handoff
 
     def _pop_plain(self, lane_kind: str) -> Optional[OperationInstance]:
         if self.policy == "pats":

@@ -105,6 +105,14 @@ def _place(value: Any, device: Any) -> Any:
     return jax.tree_util.tree_map(put, value)
 
 
+def _array_bytes(value: Any) -> int:
+    """Bytes of the host and device arrays in ``value``."""
+    import jax
+
+    return sum(getattr(x, "nbytes", 0)
+               for x in jax.tree_util.tree_leaves(value))
+
+
 def _moved(before: Any, after: Any) -> bool:
     """Whether ``_place`` replaced any leaf of ``before``."""
     import jax
@@ -227,7 +235,13 @@ class _LaneState:
     # record (the other phases record under their stage's context).
     trace_root: Optional[SpanContext] = None
     executed: int = 0
-    busy: bool = False  # currently executing (work-conserving batching)
+    # Uids of the ops the lane has claimed and not yet finished (empty
+    # while it waits): work-conserving batching and DL across lanes.
+    running: set[int] = field(default_factory=set)
+    # Accelerator lanes: inputs gathered from off the lane (host tier or
+    # another chip), ``worker.lane.<lane>.handoffs`` / ``handoff_bytes``.
+    handoffs: Any = None
+    handoff_bytes: Any = None
     # Prefetch double-buffer: next tuple whose inputs are being uploaded.
     staged: "queue.Queue[tuple[OperationInstance, threading.Event]]" = field(
         default_factory=lambda: queue.Queue(maxsize=1)
@@ -297,6 +311,12 @@ class WorkerRuntime:
             )
             for s in lanes
         ]
+        for lane in self._lanes:
+            if lane.memory is not None:
+                name = f"worker.lane.{lane.spec.kind}{lane.spec.index}"
+                lane.handoffs = self.metrics.counter(f"{name}.handoffs")
+                lane.handoff_bytes = self.metrics.counter(
+                    f"{name}.handoff_bytes")
         self._lock = threading.RLock()
         self._work_ready = threading.Condition(self._lock)
         self._stop = False
@@ -458,6 +478,12 @@ class WorkerRuntime:
     @property
     def alive(self) -> bool:
         return not self._failed
+
+    @property
+    def lane_count(self) -> int:
+        """Lanes of this worker (the Manager sizes its lease window by
+        them)."""
+        return len(self._lanes)
 
     # -- submission -----------------------------------------------------------
 
@@ -782,7 +808,7 @@ class WorkerRuntime:
         lane.clock.restart()
         while True:
             with self._lock:
-                lane.busy = False
+                lane.running = set()
                 while not self._stop and not self.scheduler:
                     self._work_ready.wait(timeout=0.25)
                 if self._stop:
@@ -793,22 +819,31 @@ class WorkerRuntime:
                     if lane.memory is not None and self.locality
                     else None
                 )
+                siblings = (
+                    self._siblings(lane) if resident is not None else None
+                )
                 if self.micro_batch > 1 and lane.memory is not None:
                     idle = sum(
                         1
                         for l in self._lanes
-                        if l.memory is not None and not l.busy
+                        if l.memory is not None and not l.running
                     )
                     limit = self.scheduler.batch_limit(self.micro_batch, idle)
                     ois = self.scheduler.pop_batch(
                         lane.spec.kind,
                         resident,
+                        siblings,
                         limit=limit,
                         batchable=self._batch_limit,
                     )
                 else:
-                    oi = self.scheduler.pop(lane.spec.kind, resident)
+                    oi = self.scheduler.pop(lane.spec.kind, resident, siblings)
                     ois = [oi] if oi is not None else []
+                if not ois and self.scheduler:
+                    # Every queued op was left to an idle sibling that
+                    # holds its inputs: wait for a commit or a claim.
+                    self._work_ready.wait(timeout=0.25)
+                    continue
                 ois = [
                     oi
                     for oi in ois
@@ -820,13 +855,30 @@ class WorkerRuntime:
                 for oi in ois:
                     self._op_claimed.add(oi.uid)
                 if ois:
-                    lane.busy = True
+                    lane.running = {oi.uid for oi in ois}
+                    if siblings and self.scheduler:
+                        # Ops a sibling left to this lane are now held
+                        # by a busy lane: let it take them.
+                        self._work_ready.notify_all()
             if not ois:
                 continue
             try:
                 self._run_batch(lane, ois)
             except BaseException as exc:  # noqa: BLE001 - recorded, not raised
                 self._record_failures([(oi, exc) for oi in ois])
+
+    def _siblings(
+        self, lane: _LaneState
+    ) -> Optional[list[tuple[DeviceMemory, set[int]]]]:
+        """What the other accelerator lanes of ``lane``'s kind hold and
+        run, for the DL pop; ``None`` when there are none."""
+        return [
+            (l.memory, l.running)
+            for l in self._lanes
+            if l is not lane
+            and l.memory is not None
+            and l.spec.kind == lane.spec.kind
+        ] or None
 
     def _batch_limit(self, oi: OperationInstance) -> int:
         """pop_batch cap: the variant's declared max batch (1 = scalar).
@@ -872,14 +924,12 @@ class WorkerRuntime:
         clock = lane.clock
         wait = clock.lap("wait")
         var = self.registry.get(ois[0].op.variant_name)
-        ctxs = [
-            OpContext(
-                chunk=oi.chunk,
-                inputs=self._gather_inputs(lane, oi),
-                lane_kind=lane.spec.kind,
-            )
-            for oi in ois
-        ]
+        ctxs = []
+        gathered: dict[int, int] = {}  # uid -> hand-off bytes
+        for oi in ois:
+            inputs, gathered[oi.uid] = self._gather_inputs(lane, oi)
+            ctxs.append(OpContext(chunk=oi.chunk, inputs=inputs,
+                                  lane_kind=lane.spec.kind))
         gather = clock.lap("gather")
         batch_fn = (
             var.batch_implementation(lane.spec.kind) if len(ois) > 1 else None
@@ -948,7 +998,7 @@ class WorkerRuntime:
                     continue
                 uid = {"uid": oi.uid}
                 self._lane_span(tracer, lane, "lane:gather", sctx, gather,
-                                args=uid)
+                                args={**uid, "bytes": gathered[oi.uid]})
                 self._lane_span(
                     tracer, lane, f"op:{oi.op.name}", sctx, dispatch,
                     cat="op",
@@ -1078,15 +1128,20 @@ class WorkerRuntime:
             except Exception:  # noqa: BLE001 - reporting is best-effort
                 pass
 
-    def _gather_inputs(self, lane: _LaneState, oi: OperationInstance) -> dict[str, Any]:
+    def _gather_inputs(
+        self, lane: _LaneState, oi: OperationInstance
+    ) -> tuple[dict[str, Any], int]:
         """Upload phase: pull dep outputs into this lane's memory.
 
         Deps already resident in *this* lane's DeviceMemory take the
         chained fast path: no host-tier read, no re-upload.  Deps held
         device-only by a sibling lane are downloaded (materialized to
-        the host tier) first — the classic cross-device route.
+        the host tier) first — the classic cross-device route.  Returns
+        the inputs and, on an accelerator lane, the array bytes of those
+        that came from off the lane (its hand-offs).
         """
         fetch_uids: list[int] = []
+        handoff_bytes = 0
         with self._lock:
             dep_objs: list[tuple[int, Any]] = []
             for uid in sorted(oi.deps):
@@ -1163,10 +1218,14 @@ class WorkerRuntime:
                         if _moved(value, placed):
                             self.uploads += 1
                         self._device_put_locked(lane, uid, placed)
+                        nbytes = _array_bytes(value)
+                        lane.handoffs += 1
+                        lane.handoff_bytes += nbytes
+                        handoff_bytes += nbytes
                     inputs[name] = lane.memory.get(uid)
                 else:
                     inputs[name] = value
-        return inputs
+        return inputs, handoff_bytes
 
     def _device_put_locked(self, lane: _LaneState, uid: int, value: Any) -> None:
         """Insert into a lane's device memory, writing any evicted

@@ -136,11 +136,14 @@ class WorkerProxy:
         peer: Peer,
         *,
         has_agent: bool,
+        lane_count: int,
         data_address: Any = None,
         rpc_timeout: float = 10.0,
     ) -> None:
         self.worker_id = worker_id
         self.peer = peer
+        # The worker's lanes, as it reported them: its lease window.
+        self.lane_count = lane_count
         # Tight per-call budget (ManagerConfig.rpc_timeout): a hung
         # worker must surface as BusTimeoutError fast, not hold the
         # Manager's dispatch path for the bus default 30s.
@@ -337,6 +340,7 @@ class ManagerEndpoint:
             peer,
             has_agent=bool(payload.get("has_agent")),
             data_address=payload.get("address"),
+            lane_count=int(payload["lanes"]),
             rpc_timeout=getattr(self.manager.cfg, "rpc_timeout", 10.0),
         )
         with self._registered:
@@ -354,7 +358,7 @@ class ManagerEndpoint:
         )
         return {
             "ok": True,
-            "window": self.manager.cfg.window,
+            "window": self.manager.window_of(proxy),
             # Workers adopt the Manager's RPC budget for their own
             # worker->manager calls: one knob governs the control plane.
             "rpc_timeout": getattr(self.manager.cfg, "rpc_timeout", 10.0),
@@ -674,6 +678,7 @@ class WorkerClient:
             {
                 "worker_id": runtime.worker_id,
                 "has_agent": runtime.agent is not None,
+                "lanes": runtime.lane_count,
                 "address": self.data_address,
                 "rack": rack,
             },
