@@ -99,16 +99,72 @@ def _obj_stats_np(values: np.ndarray, objects: np.ndarray) -> np.ndarray:
     )
 
 
-def _obj_stats_j(values: jnp.ndarray, objects: jnp.ndarray) -> jnp.ndarray:
-    flat_v, flat_o = values.reshape(-1), objects.reshape(-1)
-    n = MAX_OBJECTS + 1
-    s = jax.ops.segment_sum(flat_v, flat_o, num_segments=n)[1:]
-    s2 = jax.ops.segment_sum(flat_v * flat_v, flat_o, num_segments=n)[1:]
-    cnt = jax.ops.segment_sum(jnp.ones_like(flat_v), flat_o, num_segments=n)[1:]
+def _moments_j(s, s2, cnt, shift=0.0) -> jnp.ndarray:
+    """Per-object ``[mean, std, count]`` from the sums over objects
+    ``0..MAX_OBJECTS`` (background dropped) of ``v - shift`` and its
+    square."""
+    s, s2, cnt = s[1:], s2[1:], cnt[1:]
     safe = jnp.maximum(cnt, 1.0)
     mean = s / safe
     var = jnp.maximum(s2 / safe - mean * mean, 0.0)
+    mean = jnp.where(cnt > 0, mean + shift, 0.0)
     return jnp.stack([mean, jnp.sqrt(var), cnt], axis=-1).astype(jnp.float32)
+
+
+def _table_sums(idx: jnp.ndarray, values, *, n_bins: int, count: bool = False,
+                mxu: bool, lo_bits: int = 7) -> jnp.ndarray:
+    """Float32 ``(len(values) + count, n_bins)`` table: the sum of each
+    plane of ``values`` (and the pixel count) over the pixels of each
+    bin of ``idx``.  ``mxu`` (the ``tpu`` variants) contracts one-hots
+    on the MXU (:mod:`repro.kernels.segment_sums`): on the TPU a
+    scatter-add into a small table serializes on colliding bins.  The
+    ``accel`` variants keep one ``segment_sum`` scatter per plane, one
+    add a pixel where the contraction costs thousands on the CPU."""
+    if mxu:
+        from ..kernels import ops as K
+
+        return K.segment_sums(idx, values, n_bins=n_bins, count=count,
+                              lo_bits=lo_bits)
+    flat = idx.reshape(-1)
+    planes = [v.reshape(-1).astype(jnp.float32) for v in values]
+    if count:
+        planes.append(jnp.ones(flat.shape, jnp.float32))
+    return jnp.stack([jax.ops.segment_sum(p, flat, num_segments=n_bins)
+                      for p in planes])
+
+
+def _object_sums(objects: jnp.ndarray, values, *, count: bool = False,
+                 mxu: bool) -> jnp.ndarray:
+    """:func:`_table_sums` over objects ``0..MAX_OBJECTS``."""
+    return _table_sums(objects, values, n_bins=MAX_OBJECTS + 1, count=count,
+                       mxu=mxu)
+
+
+def _obj_stats_j(values: jnp.ndarray, objects: jnp.ndarray) -> jnp.ndarray:
+    return _moments_j(*_object_sums(objects, (values, values * values),
+                                    count=True, mxu=False))
+
+
+@jax.jit
+def _pixel_gradient_stats_j(hema: jnp.ndarray, mag: jnp.ndarray,
+                            objects: jnp.ndarray):
+    """``feat_pixel`` and ``feat_gradient`` of the fused ``tpu`` variant:
+    one MXU reduction of ``[h, h², m, m², 1]`` over objects, ``h`` and
+    ``m`` being ``hema`` and ``mag`` less their mean over the objects'
+    pixels.  Sums of squares about a value near each object's mean keep
+    the float32 digits that ``var = E[v²] - mean²`` would cancel.  The
+    jit name holds ``_gradient_stats_j``, by which
+    ``bench/metrics/_stages.py`` counts it in the feature stage, and
+    leaves out ``feature_fused``, whose roofline counts the megakernel
+    alone."""
+    fg = objects > 0
+    n_fg = jnp.maximum(fg.sum(), 1).astype(jnp.float32)
+    c_h = jnp.where(fg, hema, 0.0).sum() / n_fg
+    c_m = jnp.where(fg, mag, 0.0).sum() / n_fg
+    h, m = hema - c_h, mag - c_m
+    s_h, s2_h, s_m, s2_m, cnt = _object_sums(
+        objects, (h, h * h, m, m * m), count=True, mxu=True)
+    return _moments_j(s_h, s2_h, cnt, c_h), _moments_j(s_m, s2_m, cnt, c_m)
 
 
 # --------------------------------------------------------------------------
@@ -224,8 +280,8 @@ def haralick_cpu(state: dict) -> dict:
     return {**state, "feat_haralick": _glcm_features(glcm)}
 
 
-@jax.jit
-def _haralick_j(gray: jnp.ndarray, fg: jnp.ndarray):
+@functools.partial(jax.jit, static_argnames=("mxu",))
+def _haralick_j(gray: jnp.ndarray, fg: jnp.ndarray, mxu: bool = False):
     lo, hi = gray.min(), gray.max()
     q = ((gray - lo) / jnp.maximum(hi - lo, 1e-6) * (_GLCM_LEVELS - 1)).astype(
         jnp.int32
@@ -233,12 +289,15 @@ def _haralick_j(gray: jnp.ndarray, fg: jnp.ndarray):
     glcm = jnp.zeros((_GLCM_LEVELS, _GLCM_LEVELS), jnp.float32)
     h, w = q.shape
     for dy, dx in ((0, 1), (1, 0)):
-        a = q[: h - dy, : w - dx].reshape(-1)
-        b = q[dy:, dx:].reshape(-1)
-        m = (fg[: h - dy, : w - dx] & fg[dy:, dx:]).reshape(-1)
-        wgt = m.astype(jnp.float32)
-        glcm = glcm.at[a, b].add(wgt)
-        glcm = glcm.at[b, a].add(wgt)
+        # Foreground pairs (q[y, x], q[y+dy, x+dx]) counted in bin 8 a + b;
+        # the padded edge pixels pair with nothing.
+        pad = ((0, dy), (0, dx))
+        b = jnp.pad(q[dy:, dx:], pad)
+        both = jnp.pad(fg[: h - dy, : w - dx] & fg[dy:, dx:], pad)
+        pairs = _table_sums(q * _GLCM_LEVELS + b, (both,),
+                            n_bins=_GLCM_LEVELS ** 2, mxu=mxu, lo_bits=3)
+        pairs = pairs.reshape(_GLCM_LEVELS, _GLCM_LEVELS)
+        glcm = glcm + pairs + pairs.T
     glcm = glcm / jnp.maximum(glcm.sum(), 1e-9)
     i, j = jnp.mgrid[0:_GLCM_LEVELS, 0:_GLCM_LEVELS]
     contrast = (glcm * (i - j) ** 2).sum()
@@ -248,8 +307,9 @@ def _haralick_j(gray: jnp.ndarray, fg: jnp.ndarray):
     return jnp.stack([contrast, energy, homogeneity, entropy])
 
 
-def haralick_accel(state: dict) -> dict:
-    feats = _haralick_j(jnp.asarray(state["gray"]), jnp.asarray(state["mask"]))
+def haralick_accel(state: dict, mxu: bool = False) -> dict:
+    feats = _haralick_j(jnp.asarray(state["gray"]), jnp.asarray(state["mask"]),
+                        mxu=mxu)
     return {**state, "feat_haralick": feats}
 
 
@@ -275,9 +335,9 @@ def canny_edge_cpu(state: dict, lo: float = 20.0, hi: float = 50.0) -> dict:
     return {**state, "feat_canny": density}
 
 
-@functools.partial(jax.jit, static_argnums=())
+@functools.partial(jax.jit, static_argnames=("mxu",))
 def _canny_j(gray: jnp.ndarray, objects: jnp.ndarray, lo: float = 20.0,
-             hi: float = 50.0):
+             hi: float = 50.0, mxu: bool = False):
     from .segmentation import _recon_j  # accel reconstruction
 
     gx = _conv3_j(gray, _SOBEL_X)
@@ -285,16 +345,14 @@ def _canny_j(gray: jnp.ndarray, objects: jnp.ndarray, lo: float = 20.0,
     mag = jnp.sqrt(gx * gx + gy * gy)
     strong = (mag >= hi).astype(jnp.float32) * 255.0
     weak = (mag >= lo).astype(jnp.float32) * 255.0
-    edges = (_recon_j(strong, weak) > 0).astype(jnp.float32)
-    flat_e, flat_o = edges.reshape(-1), objects.reshape(-1)
-    n = MAX_OBJECTS + 1
-    s = jax.ops.segment_sum(flat_e, flat_o, num_segments=n)[1:]
-    cnt = jax.ops.segment_sum(jnp.ones_like(flat_e), flat_o, num_segments=n)[1:]
+    edges = _recon_j(strong, weak) > 0
+    s, cnt = _object_sums(objects, (edges,), count=True, mxu=mxu)[:, 1:]
     return s / jnp.maximum(cnt, 1.0)
 
 
-def canny_edge_accel(state: dict) -> dict:
-    density = _canny_j(jnp.asarray(state["gray"]), jnp.asarray(state["objects"]))
+def canny_edge_accel(state: dict, mxu: bool = False) -> dict:
+    density = _canny_j(jnp.asarray(state["gray"]), jnp.asarray(state["objects"]),
+                       mxu=mxu)
     return {**state, "feat_canny": density.astype(jnp.float32)}
 
 
@@ -319,25 +377,20 @@ def morphometry_cpu(state: dict) -> dict:
     return {**state, "feat_morph": feats}
 
 
-@jax.jit
-def _morphometry_j(objects: jnp.ndarray):
+@functools.partial(jax.jit, static_argnames=("mxu",))
+def _morphometry_j(objects: jnp.ndarray, mxu: bool = False):
     fg = objects > 0
     pad = jnp.pad(fg, 1)
     interior = (
         pad[:-2, 1:-1] & pad[2:, 1:-1] & pad[1:-1, :-2] & pad[1:-1, 2:]
     )
     perim = fg & ~interior
-    flat_o = objects.reshape(-1)
-    n = MAX_OBJECTS + 1
-    area = jax.ops.segment_sum(
-        fg.reshape(-1).astype(jnp.float32), flat_o, num_segments=n
-    )[1:]
-    per = jax.ops.segment_sum(
-        perim.reshape(-1).astype(jnp.float32), flat_o, num_segments=n
-    )[1:]
+    # The area is the count: every pixel of an object is foreground.
+    per, area = _object_sums(objects, (perim,), count=True, mxu=mxu)[:, 1:]
     circ = 4.0 * jnp.pi * area / jnp.maximum(per * per, 1.0)
     return jnp.stack([area, per, jnp.minimum(circ, 4.0)], -1).astype(jnp.float32)
 
 
-def morphometry_accel(state: dict) -> dict:
-    return {**state, "feat_morph": _morphometry_j(jnp.asarray(state["objects"]))}
+def morphometry_accel(state: dict, mxu: bool = False) -> dict:
+    return {**state, "feat_morph": _morphometry_j(jnp.asarray(state["objects"]),
+                                                  mxu=mxu)}

@@ -207,7 +207,11 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
     """Bind the Pallas kernels as ``tpu`` variants of their ops
     (compiled on TPU, interpret mode on the CPU backend).  The
     reconstruction kernel (:mod:`repro.kernels.morph_recon`) has two
-    users: ``recon_to_nuclei`` and ``fill_holes``."""
+    users: ``recon_to_nuclei`` and ``fill_holes``; the one-hot
+    reduction (:mod:`repro.kernels.segment_sums`) has four, the feature
+    ops' per-object sums and GLCM.  The ``gpu``/``accel`` variants keep
+    XLA's scatter-adds: on the CPU backend a scatter is one add a pixel
+    against the contraction's thousands."""
     import jax.numpy as jnp
 
     from ..kernels import ops as K
@@ -241,22 +245,21 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
 
     def feature_fused_pallas(ctx: OpContext):
         # One VMEM pass: deconv planes + Sobel |grad| of the luminance
-        # in a single HBM read, then per-object segment reductions.
-        from .features import _obj_stats_j
-
+        # in a single HBM read, then one MXU reduction over objects.
         state = dict(next(iter(ctx.inputs.values())))
         rgb = np.asarray(state["rgb"], np.float32)
         hema, eosin, mag, _ = K.feature_fused(
             jnp.asarray(rgb[..., 0]), jnp.asarray(rgb[..., 1]),
             jnp.asarray(rgb[..., 2]),
         )
-        objects = jnp.asarray(state["objects"])
+        feat_pixel, feat_gradient = F._pixel_gradient_stats_j(
+            hema, mag, jnp.asarray(state["objects"]))
         return {
             **state,
             "hema": hema,
             "eosin": eosin,
-            "feat_pixel": _obj_stats_j(hema.astype(jnp.float32), objects),
-            "feat_gradient": _obj_stats_j(mag, objects),
+            "feat_pixel": feat_pixel,
+            "feat_gradient": feat_gradient,
         }
 
     p = OP_PROFILES["color_deconv"]
@@ -272,6 +275,15 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
     reg.register("feature_fused", "tpu", feature_fused_pallas,
                  speedup=fp.gpu_speedup, transfer_impact=fp.transfer_impact,
                  batchable=fp.batchable)
+    # Per-object sums and the GLCM as one-hot MXU contractions
+    # (:mod:`repro.kernels.segment_sums`) instead of scatter-adds.
+    for name, fn in (("haralick", F.haralick_accel),
+                     ("canny_edge", F.canny_edge_accel),
+                     ("morphometry", F.morphometry_accel)):
+        p = OP_PROFILES[name]
+        reg.register(name, "tpu", _wrap(functools.partial(fn, mxu=True)),
+                     speedup=p.gpu_speedup,
+                     transfer_impact=p.transfer_impact, batchable=p.batchable)
 
 
 def run_tile(tile: np.ndarray, variant: str = "cpu") -> dict:

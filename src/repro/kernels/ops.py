@@ -25,12 +25,14 @@ from .feature_fused import feature_fused_pallas
 from .flash_attention import flash_attention_pallas
 from .mamba2_scan import mamba2_chunk_scan_pallas
 from .morph_recon import morph_recon_pallas
+from .segment_sums import segment_sums_pallas
 from .sobel_stats import sobel_stats_pallas
 
 __all__ = [
     "on_tpu",
     "color_deconv",
     "morph_recon",
+    "segment_sums",
     "sobel_stats",
     "feature_fused",
     "flash_attention",
@@ -65,6 +67,11 @@ def color_deconv(r, g, b, **kw):
 def morph_recon(marker, mask, **kw):
     kw.setdefault("interpret", _interpret())
     return morph_recon_pallas(marker, mask, **kw)
+
+
+def segment_sums(idx, values, **kw):
+    kw.setdefault("interpret", _interpret())
+    return segment_sums_pallas(idx, values, **kw)
 
 
 def sobel_stats(gray, **kw):

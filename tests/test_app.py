@@ -231,3 +231,79 @@ def test_fill_holes_pallas_program_equals_xla(case, shape):
     np.testing.assert_array_equal(want, mask | holes)
     np.testing.assert_array_equal(np.asarray(_fill_holes_accel_pallas(mask)),
                                   want)
+
+
+@pytest.fixture(scope="module")
+def segmented_256():
+    """A 256² synthetic tile through the cpu segmentation variants."""
+    from repro.app.pipeline import _SEG_ORDER, OP_IMPLS
+
+    tile = synth_tile(1, size=256, seed=3)
+    state = tile
+    for name in _SEG_ORDER:
+        state = OP_IMPLS[name][0](state)
+    return tile, state
+
+
+def _count(primitive, fn, *args) -> int:
+    """Uses of ``primitive`` in the jaxpr of ``fn(*args)``."""
+    import jax
+
+    return str(jax.make_jaxpr(fn)(*args)).count(primitive)
+
+
+@pytest.mark.parametrize("op,outputs", [
+    ("feature_fused", ("feat_pixel", "feat_gradient")),
+    ("haralick", ("feat_haralick",)),
+    ("canny_edge", ("feat_canny",)),
+    ("morphometry", ("feat_morph",)),
+])
+def test_tpu_feature_variants_match_cpu(segmented_256, op, outputs):
+    """The ``tpu`` feature variants (one-hot MXU sums, Pallas interpreted
+    here) give the NumPy ``cpu`` variants' ``feat_*`` to 1e-5 of each
+    column's largest value, with no scatter-add; the jnp ``accel``
+    variants keep ``segment_sum``'s scatter-adds."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.app import features as F
+    from repro.app.pipeline import OP_IMPLS, _feature_fused_cpu
+    from repro.core.worker import OpContext
+    from repro.core.workflow import DataChunk
+
+    tile, state = segmented_256
+    reg = VariantRegistry()
+    register_variants(reg, accel_kind="tpu", with_pallas=True)
+    ctx = OpContext(chunk=DataChunk(0, payload=tile),
+                    inputs={"bwlabel": state}, lane_kind="tpu")
+    got = reg.get(op).implementation("tpu")(ctx)
+    if op == "feature_fused":
+        want = _feature_fused_cpu(state)
+    else:
+        want = OP_IMPLS[op][0](OP_IMPLS["color_deconv"][0](state))
+    for k in outputs:
+        w = np.asarray(want[k], np.float64)
+        axis = 0 if w.ndim == 2 else None
+        err = (np.abs(np.asarray(got[k], np.float64) - w).max(axis=axis)
+               / np.maximum(np.abs(w).max(axis=axis), 1e-30))
+        assert np.all(err <= 1e-5), (k, err)
+
+    plane = jnp.asarray(np.asarray(state["gray"], np.float32))
+    objects = jnp.asarray(state["objects"])
+    fg = jnp.asarray(state["mask"])
+    accel, tpu = {
+        "feature_fused": ((F._obj_stats_j, plane, objects),
+                          (F._pixel_gradient_stats_j, plane, plane, objects)),
+        "haralick": ((F._haralick_j, plane, fg),
+                     (functools.partial(F._haralick_j, mxu=True), plane, fg)),
+        "canny_edge": ((F._canny_j, plane, objects),
+                       (functools.partial(F._canny_j, mxu=True), plane,
+                        objects)),
+        "morphometry": ((F._morphometry_j, objects),
+                        (functools.partial(F._morphometry_j, mxu=True),
+                         objects)),
+    }[op]
+    assert _count("scatter-add", *accel) > 0
+    assert _count("scatter-add", *tpu) == 0
+    assert _count("pallas_call", *tpu) > 0

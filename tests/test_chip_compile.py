@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.color_deconv import color_deconv_pallas
 from repro.kernels.feature_fused import feature_fused_pallas
 from repro.kernels.morph_recon import morph_recon_pallas
+from repro.kernels.segment_sums import segment_sums_pallas
 from repro.kernels.sobel_stats import sobel_stats_pallas
 
 SIDE = 4096
@@ -73,4 +74,25 @@ def test_fill_holes_program_compiles_for_v5e(one_chip, monkeypatch):
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     plane = jax.ShapeDtypeStruct((SIDE, SIDE), jnp.bool_, sharding=one_chip)
     compiled = _fill_holes_accel_pallas.lower(plane).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# feature op -> (dtypes of the value planes, count, bins, lo_bits): the
+# tables the ``tpu`` variants reduce into, 5 x 65x128, 2 x 65x128, 8x8.
+SEGMENT_SUMS = {
+    "feature_fused": ((jnp.float32,) * 4, True, 8193, 7),
+    "canny_edge-morphometry": ((jnp.bool_,), True, 8193, 7),
+    "haralick": ((jnp.bool_,), False, 64, 3),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(SEGMENT_SUMS))
+def test_segment_sums_compiles_for_v5e(one_chip, layout):
+    dtypes, count, n_bins, lo_bits = SEGMENT_SUMS[layout]
+    idx = jax.ShapeDtypeStruct((SIDE, SIDE), jnp.int32, sharding=one_chip)
+    planes = [jax.ShapeDtypeStruct((SIDE, SIDE), d, sharding=one_chip)
+              for d in dtypes]
+    fn = functools.partial(segment_sums_pallas, n_bins=n_bins, count=count,
+                           lo_bits=lo_bits)
+    compiled = jax.jit(lambda i, *v: fn(i, v)).lower(idx, *planes).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -73,6 +73,53 @@ def test_feature_fused(hw, stripe, dtype):
         )
 
 
+BIN_CASES = ("uniform", "one_label", "sparse", "glcm")
+
+
+def _bins(case, shape, rng):
+    """Bin indices of one label-map case, the bin count and ``lo_bits``."""
+    n = 8193
+    if case == "uniform":
+        return rng.integers(0, n, shape), n, 7
+    if case == "one_label":  # every pixel collides in one bin
+        return np.full(shape, 4321), n, 7
+    if case == "sparse":  # mostly background, empty bins, the last label
+        idx = np.where(rng.uniform(size=shape) < 0.9, 0,
+                       rng.choice([3, 130, 4097, 8192], shape))
+        idx[-1, -1] = 8192
+        return idx, n, 7
+    assert case == "glcm"  # 8 x 8 co-occurrences: index 8 a + b
+    return 8 * rng.integers(0, 8, shape) + rng.integers(0, 8, shape), 64, 3
+
+
+@pytest.mark.parametrize("hw", [(128, 128), (256, 384)])
+@pytest.mark.parametrize("case", BIN_CASES)
+@pytest.mark.parametrize("channels", [1, 2, 5])
+def test_segment_sums(hw, case, channels):
+    """One-hot MXU sums == float64 ``np.bincount`` to 1e-5 in every bin.
+    Channels as the feature ops use them: one float plane; a boolean
+    plane and the count; four float planes and the count."""
+    rng = np.random.default_rng((*hw, channels, BIN_CASES.index(case)))
+    idx, n_bins, lo_bits = _bins(case, hw, rng)
+    n_float = {1: 1, 2: 0, 5: 4}[channels]
+    values = [rng.uniform(0, 1e3, hw).astype(np.float32)
+              for _ in range(n_float)]
+    if channels == 2:
+        values.append(rng.uniform(size=hw) < 0.5)
+    count = channels > 1
+    got = ops.segment_sums(jnp.asarray(idx, jnp.int32),
+                           [jnp.asarray(v) for v in values], n_bins=n_bins,
+                           count=count, lo_bits=lo_bits, interpret=True)
+    flat = idx.reshape(-1)
+    want = [np.bincount(flat, v.reshape(-1).astype(np.float64), n_bins)
+            for v in values]
+    if count:
+        want.append(np.bincount(flat, minlength=n_bins).astype(np.float64))
+    assert got.shape == (channels, n_bins)
+    np.testing.assert_allclose(np.asarray(got, np.float64), np.stack(want),
+                               rtol=1e-5, atol=0)
+
+
 def test_on_tpu_is_cached():
     """Satellite: the backend lookup runs once per process (it is on
     the per-op dispatch path and the backend cannot change)."""
