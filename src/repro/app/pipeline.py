@@ -164,7 +164,6 @@ def register_variants(
             _wrap(accel_fn),
             speedup=p.gpu_speedup,
             transfer_impact=p.transfer_impact,
-            batchable=p.batchable,
         )
     # Fused feature megakernel variant (substitution rule: docstring).
     fp = fused_feature_profile()
@@ -176,7 +175,6 @@ def register_variants(
         _wrap(_feature_fused_accel),
         speedup=fp.gpu_speedup,
         transfer_impact=fp.transfer_impact,
-        batchable=fp.batchable,
     )
     if with_pallas:
         _register_pallas_variants(reg)
@@ -273,8 +271,7 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
                  speedup=p.gpu_speedup, transfer_impact=p.transfer_impact)
     fp = fused_feature_profile()
     reg.register("feature_fused", "tpu", feature_fused_pallas,
-                 speedup=fp.gpu_speedup, transfer_impact=fp.transfer_impact,
-                 batchable=fp.batchable)
+                 speedup=fp.gpu_speedup, transfer_impact=fp.transfer_impact)
     # Per-object sums and the GLCM as one-hot MXU contractions
     # (:mod:`repro.kernels.segment_sums`) instead of scatter-adds.
     for name, fn in (("haralick", F.haralick_accel),
@@ -283,7 +280,7 @@ def _register_pallas_variants(reg: VariantRegistry) -> None:
         p = OP_PROFILES[name]
         reg.register(name, "tpu", _wrap(functools.partial(fn, mxu=True)),
                      speedup=p.gpu_speedup,
-                     transfer_impact=p.transfer_impact, batchable=p.batchable)
+                     transfer_impact=p.transfer_impact)
 
 
 def run_tile(tile: np.ndarray, variant: str = "cpu") -> dict:

@@ -93,8 +93,7 @@ class ManagerConfig:
     # at stage completion the placement rule predicts the next holder
     # of each sink output and the completing worker pushes the bytes
     # there before the dependent lease starts, hiding the first-touch
-    # transfer.  Off by default: pull stays the baseline the benchmarks
-    # compare against.
+    # transfer.  Off by default: pull stays the baseline.
     predictive_push: bool = False
     # Data-plane flow control: cap on push bytes in flight toward any
     # single worker's ingress.  A push directive that would overflow

@@ -243,7 +243,7 @@ class ReadyScheduler:
         self.chain_affinity = chain_affinity
         # Serving deadline tier: tasks with a deadline are popped EDF,
         # ahead of the batch queue.  False = deadlines ignored (the
-        # FIFO baseline the serving benchmarks compare against).
+        # FIFO baseline the serving tests compare against).
         self.deadline_aware = deadline_aware
         # Slack-aware EDF hybrid: strict EDF preemption only when the
         # earliest deadline is within this many seconds; otherwise the
@@ -340,8 +340,7 @@ class ReadyScheduler:
 
         Never batch deeper than the ready queue can still feed the
         other idle lanes — amortization must not steal their
-        parallelism.  Shared by the threaded worker and the simulator
-        so measured batching behaviour is production behaviour.
+        parallelism.  The simulator's micro-batched dispatch reads it.
         """
         return min(micro_batch, max(1, len(self) // max(idle_lanes, 1)))
 
@@ -363,8 +362,8 @@ class ReadyScheduler:
         dispatch overhead (latency tradeoff measured in the simulator's
         batched-runtime curves).
 
-        ``batchable(head)`` returns the head op's own batch cap (its
-        variant's ``max_batch``; <= 1 disables batching) — a batched
+        ``batchable(head)`` returns the head op's own batch cap (<= 1
+        disables batching) — a batched
         implementation must never receive more contexts than its
         declared maximum.
         """

@@ -40,17 +40,6 @@ class FunctionVariant:
     speedup_fn: dict[str, SpeedupFn] = field(default_factory=dict)
     # fraction of exec time spent on host<->device transfers
     transfer_impact: float = 0.0
-    # Micro-batched dispatch: a batchable variant allows an idle
-    # accelerator lane to pop up to ``max_batch`` ready instances of
-    # this op and execute them as one (v)mapped kernel call.  Only ops
-    # whose implementation compiles once per chunk shape (regular,
-    # shape-stable) should declare this.
-    batchable: bool = False
-    max_batch: int = 1
-    # kind -> batched implementation taking a list of OpContexts and
-    # returning a same-length list of outputs.  Absent => the runtime
-    # loops the scalar implementation (still one dispatch decision).
-    batch_impls: dict[str, Callable[..., Any]] = field(default_factory=dict)
     # online estimator state: kind -> (ema_runtime, n_obs)
     _observed: dict[str, tuple[float, int]] = field(default_factory=dict)
 
@@ -67,12 +56,6 @@ class FunctionVariant:
 
     def supports(self, device_kind: str) -> bool:
         return device_kind in self.impls
-
-    def batch_implementation(
-        self, device_kind: str
-    ) -> Callable[..., Any] | None:
-        """Batched implementation for ``device_kind`` (None => loop)."""
-        return self.batch_impls.get(device_kind)
 
     def estimate_speedup(
         self, device_kind: str, meta: Mapping[str, Any] | None = None
@@ -93,13 +76,6 @@ class FunctionVariant:
         alpha = 0.3
         self._observed[device_kind] = (alpha * seconds + (1 - alpha) * ema, n + 1)
 
-    def expected_runtime(self, device_kind: str) -> float | None:
-        """Online EMA of the per-instance runtime on ``device_kind``
-        (None until observed) — feeds the adaptive micro-batch sizing
-        (``cost_model.optimal_micro_batch`` latency-budget curve)."""
-        obs = self._observed.get(device_kind)
-        return obs[0] if obs is not None else None
-
 
 class VariantRegistry:
     """Thread-safe name -> FunctionVariant map."""
@@ -117,9 +93,6 @@ class VariantRegistry:
         speedup: float | None = None,
         speedup_fn: SpeedupFn | None = None,
         transfer_impact: float | None = None,
-        batchable: bool | None = None,
-        max_batch: int | None = None,
-        batch_fn: Callable[..., Any] | None = None,
     ) -> FunctionVariant:
         with self._lock:
             var = self._variants.setdefault(name, FunctionVariant(name))
@@ -130,15 +103,6 @@ class VariantRegistry:
                 var.speedup_fn[device_kind] = speedup_fn
             if transfer_impact is not None:
                 var.transfer_impact = transfer_impact
-            if batchable is not None:
-                var.batchable = batchable
-            if batch_fn is not None:
-                var.batch_impls[device_kind] = batch_fn
-                var.batchable = True
-            if max_batch is not None:
-                var.max_batch = max_batch
-            elif var.batchable and var.max_batch <= 1:
-                var.max_batch = 8  # usable default once declared batchable
             return var
 
     def cpu(self, name: str, **kw: Any) -> Callable[[Callable], Callable]:

@@ -14,21 +14,16 @@ scheduler keeps them resident for a dependent operation, and with
 ongoing computation via a per-lane copy thread (§IV-D's
 upload/process/download pipeline).
 
-Two device-resident fast paths extend the basic model:
-
-* ``chaining=True`` — when consecutive ops of one pipeline instance
-  land on the same accelerator lane (DL reuse), the intermediate state
-  stays in that lane's DeviceMemory and the host write-back is
-  *deferred*: a chained output only materializes to the host tier when
-  a host-side consumer (sibling lane, stage-completion read, Manager
-  pull) actually needs the bytes, or when the device LRU spills it.
-  Host lanes get the same dependent-affinity: a CPU-resident chain's
-  intermediates skip the region-store round-trip and are served by
-  reference until stage completion (``host_chain_*`` stats).
-* ``micro_batch=B`` — an idle accelerator lane pops up to ``B`` ready
-  instances of the same *batchable* op (``FunctionVariant.batchable``)
-  and executes them as one batched call, amortizing per-op dispatch
-  and launch overheads over the batch.
+With ``chaining=True`` the basic model gets a device-resident fast
+path: when consecutive ops of one pipeline instance land on the same
+accelerator lane (DL reuse), the intermediate state stays in that
+lane's DeviceMemory and the host write-back is *deferred*: a chained
+output only materializes to the host tier when a host-side consumer
+(sibling lane, stage-completion read, Manager pull) actually needs the
+bytes, or when the device LRU spills it.  Host lanes get the same
+dependent-affinity: a CPU-resident chain's intermediates skip the
+region-store round-trip and are served by reference until stage
+completion (``host_chain_*`` stats).
 
 On a single-process deployment (this container) lanes are plain
 threads; on a hybrid cluster the same class drives host cores plus one
@@ -47,7 +42,6 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
-from .cost_model import TPU_V5E, op_cost_from_seconds, optimal_micro_batch
 from .scheduling import HOST_KIND, ReadyScheduler
 from .variants import VariantRegistry, registry as global_registry
 from .workflow import OperationInstance, StageInstance
@@ -236,7 +230,7 @@ class _LaneState:
     trace_root: Optional[SpanContext] = None
     executed: int = 0
     # Uids of the ops the lane has claimed and not yet finished (empty
-    # while it waits): work-conserving batching and DL across lanes.
+    # while it waits): the DL pop of its sibling lanes reads it.
     running: set[int] = field(default_factory=set)
     # Accelerator lanes: inputs gathered from off the lane (host tier or
     # another chip), ``worker.lane.<lane>.handoffs`` / ``handoff_bytes``.
@@ -260,8 +254,6 @@ class WorkerRuntime:
         locality: bool = False,
         prefetch: bool = False,
         chaining: bool = False,
-        micro_batch: int = 1,
-        batch_budget: float | None = None,
         speedups_known: bool = True,
         staging: StagingConfig | None = None,
         variant_registry: VariantRegistry | None = None,
@@ -286,12 +278,6 @@ class WorkerRuntime:
         # actually route dependents onto the holding lane.
         self.chaining = chaining
         self.locality = locality or chaining
-        self.micro_batch = max(int(micro_batch), 1)
-        # Adaptive micro-batch sizing: with a latency budget (seconds
-        # one batched launch may take), per-op batch depth comes from
-        # cost_model.optimal_micro_batch over the variant's observed
-        # runtime instead of the static max_batch cap.
-        self.batch_budget = batch_budget
         self.scheduler = ReadyScheduler(
             policy=policy,
             locality=self.locality,
@@ -503,7 +489,7 @@ class WorkerRuntime:
                 sctx = self._stage_ctx.setdefault(si.uid, ctx)
                 # Tag each op with its stage's context here, under the
                 # lock, so the lane thread can read it without taking
-                # the (contended) worker lock on the batch hot path.
+                # the (contended) worker lock on the dispatch hot path.
                 for oi in si.op_instances:
                     oi._trace_ctx = sctx  # type: ignore[attr-defined]
             local = {o.uid for o in si.op_instances}
@@ -774,8 +760,6 @@ class WorkerRuntime:
             "host_chain_hits": int(self.host_chain_hits),
             "host_chain_deferred": int(self.host_chain_deferred),
             "host_chain_writebacks": int(self.host_chain_writebacks),
-            "batches": int(self.scheduler.stats.batches),
-            "batched_ops": int(self.scheduler.stats.batched_ops),
             "push_ingested": int(self.push_ingested),
             "push_ingested_bytes": int(self.push_ingested_bytes),
             "host_fallbacks": int(self.host_fallbacks),
@@ -822,50 +806,29 @@ class WorkerRuntime:
                 siblings = (
                     self._siblings(lane) if resident is not None else None
                 )
-                if self.micro_batch > 1 and lane.memory is not None:
-                    idle = sum(
-                        1
-                        for l in self._lanes
-                        if l.memory is not None and not l.running
-                    )
-                    limit = self.scheduler.batch_limit(self.micro_batch, idle)
-                    ois = self.scheduler.pop_batch(
-                        lane.spec.kind,
-                        resident,
-                        siblings,
-                        limit=limit,
-                        batchable=self._batch_limit,
-                    )
-                else:
-                    oi = self.scheduler.pop(lane.spec.kind, resident, siblings)
-                    ois = [oi] if oi is not None else []
-                if not ois and self.scheduler:
+                oi = self.scheduler.pop(lane.spec.kind, resident, siblings)
+                if oi is None and self.scheduler:
                     # Every queued op was left to an idle sibling that
                     # holds its inputs: wait for a commit or a claim.
                     self._work_ready.wait(timeout=0.25)
                     continue
-                ois = [
-                    oi
-                    for oi in ois
-                    if oi is not None
-                    and oi.uid not in self._cancelled
-                    and oi.uid not in self._op_done
-                    and oi.uid not in self._op_claimed
-                ]
-                for oi in ois:
-                    self._op_claimed.add(oi.uid)
-                if ois:
-                    lane.running = {oi.uid for oi in ois}
-                    if siblings and self.scheduler:
-                        # Ops a sibling left to this lane are now held
-                        # by a busy lane: let it take them.
-                        self._work_ready.notify_all()
-            if not ois:
-                continue
+                if (
+                    oi is None
+                    or oi.uid in self._cancelled
+                    or oi.uid in self._op_done
+                    or oi.uid in self._op_claimed
+                ):
+                    continue
+                self._op_claimed.add(oi.uid)
+                lane.running = {oi.uid}
+                if siblings and self.scheduler:
+                    # Ops a sibling left to this lane are now held
+                    # by a busy lane: let it take them.
+                    self._work_ready.notify_all()
             try:
-                self._run_batch(lane, ois)
+                self._run_op(lane, oi)
             except BaseException as exc:  # noqa: BLE001 - recorded, not raised
-                self._record_failures([(oi, exc) for oi in ois])
+                self._record_failure(oi, exc)
 
     def _siblings(
         self, lane: _LaneState
@@ -880,39 +843,8 @@ class WorkerRuntime:
             and l.spec.kind == lane.spec.kind
         ] or None
 
-    def _batch_limit(self, oi: OperationInstance) -> int:
-        """pop_batch cap: the variant's declared max batch (1 = scalar).
-
-        With a ``batch_budget`` the cap adapts per op: the largest batch
-        whose single-launch latency (observed per-instance runtime x B)
-        still fits the budget — ``cost_model.optimal_micro_batch`` —
-        so fast ops batch deep and slow ops stay responsive, instead of
-        one config constant serving both.
-        """
-        try:
-            var = self.registry.get(oi.op.variant_name)
-        except KeyError:
-            return 1
-        cap = var.max_batch if var.batchable else 1
-        if cap <= 1 or self.batch_budget is None:
-            return cap
-        per_item = var.expected_runtime(self._accel_kind())
-        if per_item is None:
-            return cap  # nothing observed yet: static cap until then
-        return max(
-            1,
-            optimal_micro_batch(
-                op_cost_from_seconds(per_item),
-                TPU_V5E,
-                launch_overhead=0.0,
-                latency_budget=self.batch_budget,
-                max_batch=cap,
-            ),
-        )
-
-    def _run_batch(self, lane: _LaneState, ois: list[OperationInstance]) -> None:
-        """Execute one dispatch decision: a single op or a micro-batch
-        of same-op instances (one batched call, amortized launch).
+    def _run_op(self, lane: _LaneState, oi: OperationInstance) -> None:
+        """Execute one op instance on ``lane``.
 
         The lane's clock laps at every phase boundary (``LANE_PHASES``).
         An op's time, which the controllers read, runs from its gather
@@ -920,147 +852,100 @@ class WorkerRuntime:
         ``_commit`` writes back to the host are waited on first, so the
         device time is the op's own and not that of whatever blocks
         next.  A chained output is not waited on (its op's clock stops
-        at the enqueue)."""
+        at the enqueue).  An op that raises is recorded as failed and
+        the lane goes on."""
         clock = lane.clock
         wait = clock.lap("wait")
-        var = self.registry.get(ois[0].op.variant_name)
-        ctxs = []
-        gathered: dict[int, int] = {}  # uid -> hand-off bytes
-        for oi in ois:
-            inputs, gathered[oi.uid] = self._gather_inputs(lane, oi)
-            ctxs.append(OpContext(chunk=oi.chunk, inputs=inputs,
-                                  lane_kind=lane.spec.kind))
+        var = self.registry.get(oi.op.variant_name)
+        inputs, gathered = self._gather_inputs(lane, oi)
+        ctx = OpContext(chunk=oi.chunk, inputs=inputs, lane_kind=lane.spec.kind)
         gather = clock.lap("gather")
-        batch_fn = (
-            var.batch_implementation(lane.spec.kind) if len(ois) > 1 else None
-        )
-        kind = (
-            lane.spec.kind
-            if batch_fn is not None or var.supports(lane.spec.kind)
-            else HOST_KIND
-        )
+        kind = lane.spec.kind if var.supports(lane.spec.kind) else HOST_KIND
         with self._lock:
             key = (var.name, kind)
-            self.variant_runs[key] = self.variant_runs.get(key, 0) + len(ois)
+            self.variant_runs[key] = self.variant_runs.get(key, 0) + 1
             if kind != lane.spec.kind:
-                self.host_fallbacks += len(ois)
-        failures: list[tuple[OperationInstance, BaseException]] = []
-        if batch_fn is not None:
-            for oi in ois:
-                self._hook_op_start(oi)
-            outs = batch_fn(ctxs)
-            if len(outs) != len(ctxs):
-                raise RuntimeError(
-                    f"batch implementation of {var.name!r} returned "
-                    f"{len(outs)} outputs for {len(ctxs)} contexts"
-                )
-            pairs = list(zip(ois, outs))
-        else:
-            # Scalar loop: isolate failures to the failing chunk so one
-            # malformed tile cannot poison its batch-mates' results.
-            impl = var.implementation(lane.spec.kind)
-            pairs = []
-            for oi, ctx in zip(ois, ctxs):
-                try:
-                    self._hook_op_start(oi)
-                    pairs.append((oi, impl(ctx)))
-                except BaseException as exc:  # noqa: BLE001 - recorded
-                    failures.append((oi, exc))
+                self.host_fallbacks += 1
+        failure: Optional[BaseException] = None
+        try:
+            self._hook_op_start(oi)
+            out = var.implementation(lane.spec.kind)(ctx)
+        except BaseException as exc:  # noqa: BLE001 - recorded
+            failure = exc
         dispatch = clock.lap("dispatch")
-        synced = self._sync(lane, pairs)
+        synced = failure is None and self._sync(lane, oi, out)
         sync = clock.lap("sync")
         elapsed = (sync[1] - gather[0]) * 1e-9
-        lane.executed += len(ois)
-        self.op_runtime_hist.observe(elapsed / len(ois))
+        lane.executed += 1
+        self.op_runtime_hist.observe(elapsed)
         with self._lock:
-            per_op = elapsed / len(ois)
-            for oi in ois:
-                suid = oi.stage_instance.uid
-                self._stage_exec[suid] = (
-                    self._stage_exec.get(suid, 0.0) + per_op
-                )
-        # The tracer is read once: a span set is all or nothing per batch.
+            suid = oi.stage_instance.uid
+            self._stage_exec[suid] = self._stage_exec.get(suid, 0.0) + elapsed
+        # The tracer is read once: a span set is all or nothing per op.
         tracer = self.tracer
+        # The ctx tag was written by submit_stage under the worker lock
+        # before the op could queue, so the lock-free read here is safe;
+        # unsampled ops carry no tag and cost one getattr.
+        sctx = getattr(oi, "_trace_ctx", None)
         if tracer is not None:
-            # One span per phase per op instance (batch-mates share the
-            # times), each under its own stage's context, on the lane's
-            # row; the wait before it under the lane's own root.  The
-            # ctx tag was written by submit_stage under the worker lock
-            # before the op could queue, so the lock-free read here is
-            # safe; unsampled ops carry no tag and cost one getattr.
+            # One span per phase, under the op's stage context, on the
+            # lane's row; the wait before it under the lane's own root.
             if lane.trace_root is None:
                 lane.trace_root = tracer.start_trace()
             clock.anchor()
             self._lane_span(tracer, lane, "lane:wait", lane.trace_root, wait)
-            for oi in ois:
-                sctx = getattr(oi, "_trace_ctx", None)
-                if sctx is None:
-                    continue
+            if sctx is not None:
                 uid = {"uid": oi.uid}
                 self._lane_span(tracer, lane, "lane:gather", sctx, gather,
-                                args={**uid, "bytes": gathered[oi.uid]})
-                self._lane_span(
-                    tracer, lane, f"op:{oi.op.name}", sctx, dispatch,
-                    cat="op",
-                    args={"uid": oi.uid, "batch": len(ois),
-                          "synced": oi.uid in synced},
-                )
-                if oi.uid in synced:
+                                args={**uid, "bytes": gathered})
+                self._lane_span(tracer, lane, f"op:{oi.op.name}", sctx,
+                                dispatch, cat="op",
+                                args={**uid, "synced": synced})
+                if synced:
                     self._lane_span(tracer, lane, "lane:sync", sctx, sync,
                                     args=uid)
         if self.observe_runtimes:
-            var.observe_runtime(lane.spec.kind, elapsed / len(ois))
+            var.observe_runtime(lane.spec.kind, elapsed)
             if self.scheduler.policy == "pats":
                 # Keep the ready queue consistent with the shifted EMA —
                 # but only pay the O(queue) re-sort when the estimate
                 # materially moved (PATS only needs relative order).
-                est = var.estimate_speedup(
-                    self._accel_kind(), ois[0].chunk.meta
-                )
+                est = var.estimate_speedup(self._accel_kind(), oi.chunk.meta)
                 last = self._reorder_est.get(var.name)
                 if last is None or abs(est - last) > 0.1 * max(last, 1e-9):
                     self._reorder_est[var.name] = est
                     with self._lock:
                         self.scheduler.reestimate(self._estimate_of)
-        start = sync[1]
-        for oi, out in pairs:
-            d2h = self._commit(lane, oi, out)
-            commit = (start, clock.lap("commit")[1])
-            start = commit[1]
-            sctx = getattr(oi, "_trace_ctx", None)
-            if tracer is not None and sctx is not None:
-                parent = self._lane_span(
-                    tracer, lane, "lane:commit", sctx, commit,
-                    args={"uid": oi.uid},
+        if failure is not None:
+            self._record_failure(oi, failure)
+            return
+        d2h = self._commit(lane, oi, out)
+        commit = (sync[1], clock.lap("commit")[1])
+        if tracer is not None and sctx is not None:
+            parent = self._lane_span(
+                tracer, lane, "lane:commit", sctx, commit, args={"uid": oi.uid},
+            )
+            if d2h is not None and parent is not None:
+                self._lane_span(
+                    tracer, lane, "lane:d2h", parent, d2h[:2],
+                    args={"uid": oi.uid, "bytes": d2h[2]},
                 )
-                if d2h is not None and parent is not None:
-                    self._lane_span(
-                        tracer, lane, "lane:d2h", parent, d2h[:2],
-                        args={"uid": oi.uid, "bytes": d2h[2]},
-                    )
-        self._record_failures(failures)
 
-    def _sync(
-        self, lane: _LaneState, pairs: list[tuple[OperationInstance, Any]]
-    ) -> set[int]:
-        """Wait on the device for the outputs that ``_commit`` will write
-        back to the host (all of an accelerator lane's outputs but the
-        chained ones), before the worker lock is taken: the download
-        that follows only copies.  Returns the uids waited on."""
-        if lane.memory is None or not pairs:
-            return set()
+    def _sync(self, lane: _LaneState, oi: OperationInstance, out: Any) -> bool:
+        """Wait on the device for ``out`` when ``_commit`` will write it
+        back to the host (every output of an accelerator lane but a
+        chained one), before the worker lock is taken: the download that
+        follows only copies.  Returns whether it waited."""
+        if lane.memory is None:
+            return False
         if self.chaining:
             with self._lock:
-                pairs = [
-                    (oi, out) for oi, out in pairs
-                    if not self._chainable_locked(oi)
-                ]
-            if not pairs:
-                return set()
+                if self._chainable_locked(oi):
+                    return False
         import jax
 
-        jax.block_until_ready([out for _, out in pairs])
-        return {oi.uid for oi, _ in pairs}
+        jax.block_until_ready(out)
+        return True
 
     def _lane_span(
         self,
@@ -1096,37 +981,29 @@ class WorkerRuntime:
         if hook is not None:
             hook(self, oi)
 
-    def _record_failures(
-        self, failures: list[tuple[OperationInstance, BaseException]]
-    ) -> None:
-        """Record op failures and report each newly-failed stage upstream
-        exactly once.  The stage's remaining ops are cancelled — a failed
-        stage can never complete, so leaving them queued only wastes
-        lanes — and ``on_stage_failed`` fires with the worker lock
-        released (lock order is manager -> worker).  A killed worker does
-        not report: death attribution is the Manager's job."""
-        if not failures:
-            return
-        report: list[tuple[StageInstance, BaseException]] = []
+    def _record_failure(self, oi: OperationInstance, exc: BaseException) -> None:
+        """Record an op failure and report its stage upstream exactly
+        once.  The stage's remaining ops are cancelled — a failed stage
+        can never complete, so leaving them queued only wastes lanes —
+        and ``on_stage_failed`` fires with the worker lock released
+        (lock order is manager -> worker).  A killed worker does not
+        report: death attribution is the Manager's job."""
         with self._lock:
-            for oi, exc in failures:
-                self.errors.append((oi.uid, exc))
-                si = oi.stage_instance
-                if si.uid in self._failed_stages:
-                    continue
+            self.errors.append((oi.uid, exc))
+            si = oi.stage_instance
+            first = si.uid not in self._failed_stages
+            if first:
                 self._failed_stages.add(si.uid)
                 for o in si.op_instances:
                     if o.uid not in self._op_done:
                         self._cancelled.add(o.uid)
-                report.append((si, exc))
             self._work_ready.notify_all()
-        if not self.alive or self.on_stage_failed is None:
+        if not first or not self.alive or self.on_stage_failed is None:
             return
-        for si, exc in report:
-            try:
-                self.on_stage_failed(si, f"{type(exc).__name__}: {exc}")
-            except Exception:  # noqa: BLE001 - reporting is best-effort
-                pass
+        try:
+            self.on_stage_failed(si, f"{type(exc).__name__}: {exc}")
+        except Exception:  # noqa: BLE001 - reporting is best-effort
+            pass
 
     def _gather_inputs(
         self, lane: _LaneState, oi: OperationInstance

@@ -99,7 +99,7 @@ def mamba2_chunk_scan(decay, inc, **kw):
     return mamba2_chunk_scan_pallas(decay, inc, **kw)
 
 
-#: oracle references, re-exported for tests/benchmarks
+#: oracle references, re-exported for the tests
 oracles = {
     "color_deconv": ref.color_deconv_ref,
     "morph_recon": ref.morph_recon_ref,

@@ -13,8 +13,8 @@ mesh for every assigned architecture and input shape, with
 * collective bytes parsed from the optimized HLO (all-gather /
   all-reduce / reduce-scatter / all-to-all / collective-permute),
 
-recorded per cell into ``benchmarks/out/dryrun_results.json`` for
-EXPERIMENTS.md §Dry-run / §Roofline.
+recorded per cell into ``--out`` (default ``dryrun_results.json`` in
+the working directory).
 
 Usage::
 
@@ -49,8 +49,6 @@ from .sharding import (
     param_specs,
     to_shardings,
 )
-
-RESULTS = Path(__file__).resolve().parents[3] / "benchmarks" / "out"
 
 # TPU v5e hardware constants (roofline denominators).
 PEAK_FLOPS = 197e12        # bf16 per chip
@@ -313,11 +311,11 @@ def main() -> None:
     ap.add_argument("--causal-skip", action="store_true")
     ap.add_argument("--kv-quant", action="store_true")
     ap.add_argument("--opt8bit", action="store_true")
-    ap.add_argument("--out", default=str(RESULTS / "dryrun_results.json"))
+    ap.add_argument("--out", default="dryrun_results.json")
     args = ap.parse_args()
 
-    RESULTS.mkdir(parents=True, exist_ok=True)
     out_path = Path(args.out)
+    out_path.parent.mkdir(parents=True, exist_ok=True)
     results: list[dict] = []
     if out_path.exists():
         results = json.loads(out_path.read_text())

@@ -10,10 +10,10 @@ drawn from a Zipf popularity distribution over ``n_tiles`` — hot tiles
 dominate, mirroring map-viewer traffic over a whole-slide image where
 the current viewport's tiles are requested by many users at once.
 
-The same generator drives the threaded runtime (``benchmarks/serving``
-replays the trace against a real Manager) and the discrete-event
-simulator (``SimConfig.arrival_rate``), so measured and simulated
-latency curves come from identical traces.
+The same generator drives the threaded runtime (a
+:class:`~repro.serving.gateway.RequestGateway` in front of a real
+Manager) and the discrete-event simulator (``SimConfig.arrival_rate``),
+so measured and simulated latency curves come from identical traces.
 """
 
 from __future__ import annotations

@@ -28,7 +28,7 @@ Module map
   ``select_lease``: the locality-aware lease-placement rule with a
   ``transfer_impact``-style tie-break mirroring ``core/scheduling.py``.
 * :mod:`repro.staging.config`    — ``StagingConfig``: per-worker tier
-  stack construction shared by Worker, Manager, and benchmarks.
+  stack construction shared by Worker, Manager, and tests.
 
 How it composes with the paper's runtime: ``core/scheduling.py`` keeps
 locality *within* a node (device-memory reuse, §IV-C); this package

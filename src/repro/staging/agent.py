@@ -106,7 +106,7 @@ class StagingAgent:
         # The pull is deferred until the deadline so push and pull don't
         # move the same bytes twice; overdue keys re-enter the queue.
         self._deferred: dict[RegionKey, float] = {}
-        # Counters read by benchmarks / tests — int-like cells in the
+        # Counters read by tests and telemetry — int-like cells in the
         # shared metrics registry (`stats()` stays the thin int view).
         self.registry = registry or MetricsRegistry()
         c = lambda name: self.registry.counter(f"staging.{name}")  # noqa: E731

@@ -1,4 +1,4 @@
-"""Staging configuration shared by Worker, Manager, and benchmarks."""
+"""Staging configuration shared by Worker, Manager, and tests."""
 
 from __future__ import annotations
 

@@ -61,7 +61,7 @@ class RegionStore:
         self.tiers = list(tiers)
         self.demote = demote
         self._lock = threading.RLock()
-        # Movement counters (cluster benchmarks read these) — int-like
+        # Movement counters (tests and telemetry read these) — int-like
         # cells in the shared metrics registry.
         self.registry = registry or MetricsRegistry()
         self.promotions = self.registry.counter("store.promotions")

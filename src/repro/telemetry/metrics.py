@@ -4,7 +4,7 @@ Design constraints, in order:
 
 1. **Drop-in for the ad-hoc counters they replace.**  Seven subsystems
    kept plain-int attributes (``self.recovered_leases += 1``) that
-   tests and benchmarks read directly (``assert mgr.recovered_leases
+   tests read directly (``assert mgr.recovered_leases
    >= 1``).  :class:`Counter`/:class:`Gauge` are therefore *int-like*:
    in-place ``+=``/``-=`` mutate the shared cell, and comparisons,
    arithmetic, ``int()``/``float()``/``bool()`` all behave like the

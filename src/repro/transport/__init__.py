@@ -27,14 +27,15 @@ Module map
   Manager-side face of a remote worker), ``spawn_worker``/``worker_main``
   (real OS-process workers).
 * :mod:`repro.transport.demo`      — module-level demo workload shared
-  by multiprocess tests and benchmarks.
+  by the multiprocess tests.
 
 How it composes with the paper's runtime: §III-B's Manager/Worker
 protocol is MPI messages; here the same protocol is expressed once
 against the bus contract and deployed per-backend — in-process calls
 where the seed ran, real sockets across OS processes — so control-
 plane costs (round-trips, batching amortization) become measurable
-(``benchmarks/transport.py``) instead of structurally free.
+(the bus and codec counters, ``SimConfig.rpc_latency_us``) instead of
+structurally free.
 """
 
 from .bus import (

@@ -113,8 +113,7 @@ class MessageBus(ABC):
         self._lock = threading.Lock()
         # Aggregate traffic counters served from the shared metrics
         # registry (int-like cells: existing `bus.messages_sent += 1`
-        # sites and comparisons work unchanged; benchmarks and tests
-        # read these).
+        # sites and comparisons work unchanged; tests read these).
         self.registry = registry or MetricsRegistry()
         self.messages_sent = self.registry.counter("bus.messages_sent")
         self.frames_sent = self.registry.counter("bus.frames_sent")

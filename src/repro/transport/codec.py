@@ -87,7 +87,7 @@ class WireCodec:
 
     def __init__(self, codecs: Optional[list[Codec]] = None):
         self.codecs: list[Codec] = list(codecs) if codecs else [_ARRAY_CODEC]
-        # Traffic counters (benchmarks read these).
+        # Traffic counters (tests read these).
         self.encoded_bytes = 0
         self.decoded_bytes = 0
         self.pickle_fallbacks = 0

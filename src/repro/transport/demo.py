@@ -2,7 +2,7 @@
 
 Spawned worker processes rebuild their VariantRegistry from a
 ``"module:factory"`` path (locals don't survive ``spawn``), so the
-transport tests and benchmarks share this module-level two-stage
+transport tests share this module-level two-stage
 pipeline: ``produce`` emits a deterministic tile-sized array,
 ``consume`` reduces it.  Output values depend only on the chunk id,
 which is what lets a socket-bus run be compared bit-for-bit against an
@@ -140,8 +140,8 @@ def dataplane_registry() -> VariantRegistry:
     """Transfer-bound variants of the demo pipelines (~4 MB regions,
     sleep-modeled compute): what a produce->consume or fan-in edge
     costs is dominated by where its bytes flow and when they start
-    moving, which is exactly what the coordinator-bypass benchmarks
-    need to expose.  Serves both ``demo_workflow`` and
+    moving, which is exactly what the coordinator-bypass tests need
+    to expose.  Serves both ``demo_workflow`` and
     ``fanin_workflow``."""
     reg = VariantRegistry()
     reg.register("produce", "cpu", _dp_produce)
@@ -170,8 +170,8 @@ def demo_concrete(n_chunks: int) -> ConcreteWorkflow:
 # ``combine`` consumes TWO upstream regions; ``produce_b`` is slower than
 # ``produce_a``, so on a two-worker cluster (window 1, FIFO) the first
 # chunk's a and b deterministically land on different workers and every
-# combine has at least one remote input — the data-plane tests and
-# benchmarks need cross-worker traffic they can rely on.
+# combine has at least one remote input — the data-plane tests need
+# cross-worker traffic they can rely on.
 
 
 def _produce_a(ctx) -> np.ndarray:

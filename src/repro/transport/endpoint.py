@@ -225,7 +225,7 @@ class WorkerProxy:
         self._send("push_request", (key, address))
 
     def stats(self) -> dict:
-        """Remote runtime + transport counters (benchmarks/tests)."""
+        """Remote runtime + transport counters (for tests and telemetry)."""
         try:
             return dict(self.peer.call("get_stats", timeout=self.rpc_timeout))
         except BusError:
@@ -601,7 +601,7 @@ class WorkerClient:
         # Sibling peer cache: data-plane address -> dialed Peer.
         self._siblings: dict[Any, Peer] = {}
         self._sibling_lock = threading.Lock()
-        # Data-plane traffic counters (benchmarks/tests).  Registered
+        # Data-plane traffic counters (for tests and telemetry).  Registered
         # into the runtime's MetricsRegistry when it has one so a single
         # ``get_stats`` snapshot carries them; plain ints otherwise.
         metrics = getattr(runtime, "metrics", None)
@@ -974,8 +974,6 @@ class WorkerSpec:
     lanes: tuple[tuple[str, int], ...] = (("cpu", 0),)
     policy: str = "fcfs"
     chaining: bool = False
-    micro_batch: int = 1
-    batch_budget: Optional[float] = None  # adaptive micro-batch sizing
     staging: bool = True               # build a StagingConfig (prefetch agent)
     host_budget_bytes: Optional[int] = None
     data_plane: bool = True            # serve worker-to-worker transfers
@@ -1053,8 +1051,6 @@ def worker_main(address: str, spec: WorkerSpec) -> None:
         lanes=tuple(LaneSpec(kind, idx) for kind, idx in spec.lanes),
         policy=spec.policy,
         chaining=spec.chaining,
-        micro_batch=spec.micro_batch,
-        batch_budget=spec.batch_budget,
         staging=staging,
         variant_registry=registry,
         registry=metrics,

@@ -1,7 +1,9 @@
-"""Device-resident chaining, micro-batched dispatch, and the PATS
-online-EMA path (hypothesis-free: always collected)."""
+"""Device-resident chaining, the scheduler's and simulator's
+micro-batched dispatch, and the PATS online-EMA path (hypothesis-free:
+always collected)."""
 
 import itertools
+import threading
 import time
 
 import numpy as np
@@ -232,49 +234,6 @@ def test_chaining_skips_host_materialization():
         rt.stop()
 
 
-# -- worker runtime: micro-batching -------------------------------------------
-
-
-def test_worker_micro_batch_executes_batched_and_correct():
-    reg = VariantRegistry()
-    calls = {"batched": 0, "scalar": 0}
-
-    def scalar(ctx):
-        calls["scalar"] += 1
-        time.sleep(0.002)
-        return float(ctx.chunk.chunk_id) * 2.0
-
-    def batched(ctxs):
-        calls["batched"] += 1
-        time.sleep(0.002)  # one launch for the whole batch
-        return [float(c.chunk.chunk_id) * 2.0 for c in ctxs]
-
-    reg.register("double", "cpu", scalar)
-    reg.register("double", "gpu", scalar, speedup=10.0, batch_fn=batched,
-                 max_batch=8)
-    wf = AbstractWorkflow.chain(
-        "batch", [Stage.single(Operation("double"))]
-    )
-    cw = ConcreteWorkflow.replicate(wf, [DataChunk(i) for i in range(16)])
-    rt = WorkerRuntime(
-        0, lanes=(LaneSpec("gpu", 0),), policy="fcfs", micro_batch=8,
-        variant_registry=reg,
-    )
-    rt.start()
-    try:
-        for si in cw.stage_instances.values():
-            rt.submit_stage(si)
-        assert rt.drain(timeout=60.0)
-        assert not rt.errors
-        for si in cw.stage_instances.values():
-            out = rt.output_of(si.op_instances[0].uid)
-            assert out == si.chunk.chunk_id * 2.0
-        assert calls["batched"] > 0
-        assert rt.scheduler.stats.batched_ops > 0
-    finally:
-        rt.stop()
-
-
 def test_resubmitted_stage_does_not_duplicate_ops():
     """A heartbeat-slander rejoin re-leases recovered stages to the
     worker that still holds them; submit_stage must be idempotent or
@@ -305,47 +264,49 @@ def test_resubmitted_stage_does_not_duplicate_ops():
 
 
 def test_micro_batch_isolates_single_op_failure():
-    """One malformed chunk in a micro-batch must not poison its
-    batch-mates: healthy ops commit, only the bad one errors."""
+    """One malformed chunk must not poison the lane: its stage alone is
+    reported failed, once, and the same lane runs every other chunk's op."""
     reg = VariantRegistry()
+    lanes_run: dict[int, str] = {}
 
     def flaky(ctx):
+        lanes_run[ctx.chunk.chunk_id] = threading.current_thread().name
         if ctx.chunk.chunk_id == 3:
             raise ValueError("malformed tile")
         return ctx.chunk.chunk_id * 2.0
 
     reg.register("flaky", "cpu", flaky)
-    reg.register("flaky", "gpu", flaky, speedup=5.0, batchable=True,
-                 max_batch=8)
+    reg.register("flaky", "gpu", flaky, speedup=5.0)
     wf = AbstractWorkflow.chain("iso", [Stage.single(Operation("flaky"))])
     cw = ConcreteWorkflow.replicate(wf, [DataChunk(i) for i in range(8)])
     rt = WorkerRuntime(0, lanes=(LaneSpec("gpu", 0),), policy="fcfs",
-                       micro_batch=8, variant_registry=reg)
+                       variant_registry=reg)
+    failed: list[tuple[int, str]] = []
+    rt.on_stage_failed = lambda si, why: failed.append((si.chunk.chunk_id, why))
     rt.start()
     try:
         for si in cw.stage_instances.values():
             rt.submit_stage(si)
         deadline = time.monotonic() + 30.0
-        while time.monotonic() < deadline and len(rt.completion_order) < 7:
+        while time.monotonic() < deadline and (
+            len(rt.completion_order) < 7 or not failed
+        ):
             time.sleep(0.01)
+        assert failed == [(3, "ValueError: malformed tile")]
         assert len(rt.completion_order) == 7  # all but the bad chunk
         assert len(rt.errors) == 1
         uid, exc = rt.errors[0]
         assert isinstance(exc, ValueError)
+        assert cw.op_instances[uid].chunk.chunk_id == 3
+        for si in cw.stage_instances.values():
+            if si.chunk.chunk_id != 3:
+                assert rt.output_of(si.op_instances[0].uid) == (
+                    si.chunk.chunk_id * 2.0
+                )
+        assert set(lanes_run) == set(range(8))
+        assert set(lanes_run.values()) == {"worker0-gpu0"}
     finally:
         rt.stop()
-
-
-def test_batch_fn_only_registration_is_usable():
-    """Registering just a batch_fn must yield a batchable variant with
-    a >1 max_batch, or the batched implementation would be dead code."""
-    reg = VariantRegistry()
-    var = reg.register(
-        "v", "gpu", lambda ctx: None, batch_fn=lambda ctxs: [None] * len(ctxs)
-    )
-    assert var.batchable and var.max_batch > 1
-    assert var.batch_implementation("gpu") is not None
-    assert var.batch_implementation("cpu") is None
 
 
 # -- replication-aware eviction (satellite) -----------------------------------
@@ -440,16 +401,6 @@ def test_simulator_batch_size_sweep_monotone():
     assert spans[-1] < spans[0] * 0.85
     for a, b in zip(spans, spans[1:]):
         assert b < a * 1.05  # never materially worse
-
-
-@pytest.mark.slow
-def test_bench_pr2_meets_acceptance(tmp_path):
-    from benchmarks.pr2 import bench_pr2
-
-    rows = bench_pr2(tmp_path / "BENCH_PR2.json")
-    speed = [v for n, v, _ in rows if n == "pr2/sim/speedup_on_vs_off"][0]
-    assert speed >= 1.3
-    assert (tmp_path / "BENCH_PR2.json").exists()
 
 
 # -- worker runtime: chained CPU lanes ----------------------------------------

@@ -1,6 +1,7 @@
 """Coordinator-bypass data plane: worker-to-worker region transfer,
 predictive push of sink outputs, holder-cache invalidation, segmented
-bulk frames, byte-keyed journal compaction, adaptive micro-batching."""
+bulk frames, byte-keyed journal compaction, the simulator's adaptive
+micro-batching."""
 
 from __future__ import annotations
 
@@ -12,8 +13,7 @@ import pytest
 
 import repro.transport as T
 from repro.core import LaneSpec, Manager, ManagerConfig, WorkerRuntime
-from repro.core.variants import VariantRegistry
-from repro.core.workflow import ConcreteWorkflow, DataChunk
+from repro.core.workflow import ConcreteWorkflow
 from repro.staging import DirectoryService, StagingConfig
 from repro.staging.agent import StagingAgent
 from repro.staging.store import RegionStore, op_key
@@ -410,39 +410,8 @@ def test_journal_rehydrate_bounded_at_fig14_scale(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# adaptive micro-batch sizing (cost_model.optimal_micro_batch wired in)
+# the simulator's adaptive micro-batch sizing (cost_model.optimal_micro_batch)
 # --------------------------------------------------------------------------
-
-
-def test_worker_batch_limit_adapts_to_latency_budget():
-    reg = VariantRegistry()
-    reg.register(
-        "op", "gpu", lambda ctx: None, batchable=True, max_batch=32
-    )
-    var = reg.get("op")
-    var.observe_runtime("gpu", 0.01)  # 10 ms per instance
-    rt = WorkerRuntime(
-        0,
-        lanes=(LaneSpec("gpu", 0),),
-        variant_registry=reg,
-        micro_batch=32,
-        batch_budget=0.05,  # one launch may take 50 ms -> B = 5
-    )
-    from repro.core.workflow import Operation, OperationInstance, Stage
-
-    cw = ConcreteWorkflow.replicate(
-        __import__("repro.core.workflow", fromlist=["AbstractWorkflow"])
-        .AbstractWorkflow("w", (Stage.single(Operation("op")),)),
-        [DataChunk(0)],
-    )
-    oi = next(iter(cw.op_instances.values()))
-    assert rt._batch_limit(oi) == 5  # noqa: SLF001
-    # Without a budget the static variant cap rules.
-    rt.batch_budget = None
-    assert rt._batch_limit(oi) == 32  # noqa: SLF001
-    # A tighter budget shrinks the batch; never below 1.
-    rt.batch_budget = 0.001
-    assert rt._batch_limit(oi) == 1  # noqa: SLF001
 
 
 def test_sim_adaptive_batch_respects_budget():

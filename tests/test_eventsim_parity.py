@@ -15,8 +15,7 @@ The two models are *different physics* under heavy contention (that is
 the point of the rewrite — store-and-forward exaggerates uplink
 serialization), so the matrix pins moderate-contention cells where an
 honest engine must agree with the legacy one; the contention delta
-itself is measured in benchmarks/eventsim.py and discussed in
-docs/simulator.md.
+itself is discussed in docs/simulator.md.
 """
 
 from __future__ import annotations

@@ -348,6 +348,26 @@ def test_sim_serving_completes_and_reports_percentiles():
     assert r.latency_p99 >= r.latency_p50 > 0.0
 
 
+def test_sim_admission_bounds_admitted_p99_at_overload():
+    """Offered 1.5x the measured capacity, a queue-depth cap keeps the
+    admitted p99 within 3x the half-load p99 by shedding, while the
+    uncontrolled queue admits everything and its tail grows past it."""
+    base = dict(n_nodes=2, serve_duration_s=30.0, tenants={"t0": 1.0},
+                gateway_inflight=16, seed=17)
+
+    def run(rate, cap):
+        return _serve_sim(**base, arrival_rate=rate,
+                          admission_queue_cap=cap)[1].run()
+
+    sat = run(50.0, 10_000)
+    mu = sat.completed_requests / sat.makespan
+    half, on, off = run(0.5 * mu, 16), run(1.5 * mu, 16), run(1.5 * mu, None)
+    assert half.completed_ok and on.completed_ok and off.completed_ok
+    assert on.shed_requests > 0 and off.shed_requests == 0
+    assert on.latency_p99 <= 3.0 * half.latency_p99
+    assert off.latency_p99 > on.latency_p99
+
+
 def test_sim_two_tenant_fairness_tracks_weights():
     """Sustained 2:1 overload: completions inside the arrival window
     split by the configured weights within 10%."""

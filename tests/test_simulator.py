@@ -124,5 +124,4 @@ def test_multi_node_strong_scaling():
                                        locality=True, prefetch=True))
     speedup = r2.makespan / r8.makespan
     assert speedup > 2.7  # >=67% scaling efficiency from 2 to 8 nodes
-    # (drain-tail dominated at this reduced tile count; the full-scale
-    # Fig 14 run in benchmarks/ shows 76% at 100 nodes.)
+    # (drain-tail dominated at this reduced tile count.)

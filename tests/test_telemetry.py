@@ -553,8 +553,7 @@ def test_span_propagation_across_process_boundary():
 
 def test_counter_increment_overhead_guard():
     """Regression guard: a registry counter increment stays within 40x
-    of a plain int increment (absolute cost ~1us; the benchmarks
-    measure the end-to-end <=2% bar)."""
+    of a plain int increment (absolute cost ~1us)."""
     reg = MetricsRegistry("t")
     c = reg.counter("x")
     n = 50_000
